@@ -202,14 +202,15 @@ def _rec_phash(lsn: int, size: int, payload) -> int:
     which the Pallas kernel in kernels/checksum evaluates at VMEM
     bandwidth on TPU (the jnp oracle elsewhere — identical value by
     construction).  Seeded with (lsn, size) for the same soundness
-    reason as _rec_crc.
+    reason as _rec_crc.  The (seed || payload) lane row has the layout
+    the recovery scan's batched validator builds (_first_bad_payload).
     """
-    from ..kernels.checksum.ops import tensor_checksum
-    buf = np.concatenate([
-        np.frombuffer(_SEED.pack(lsn, size), dtype=np.uint8),
-        np.frombuffer(payload, dtype=np.uint8),
-    ])
-    return int(tensor_checksum(buf))
+    from ..kernels.checksum.ops import tensor_checksum_batch
+    row = np.zeros((1, (_SEED.size + size + 3) // 4), dtype=np.uint32)
+    row_u8 = row.view(np.uint8)
+    row_u8[0, :_SEED.size] = np.frombuffer(_SEED.pack(lsn, size), np.uint8)
+    row_u8[0, _SEED.size:_SEED.size + size] = np.frombuffer(payload, np.uint8)
+    return int(tensor_checksum_batch(row)[0])
 
 
 def _rec_checksum(lsn: int, size: int, payload, phash: bool) -> int:

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _quantize(x, scale, key=None):
@@ -62,7 +61,7 @@ def quantized_allreduce(x: jax.Array, mesh: Mesh, axis: str,
                         key: Optional[jax.Array] = None) -> jax.Array:
     """Convenience wrapper: shard_map'd compressed_psum for a tensor
     replicated over ``axis`` (e.g. per-pod gradient replicas)."""
-    fn = shard_map(partial(compressed_psum, axis=axis, key=key),
-                   mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-                   check_rep=False)
+    fn = jax.shard_map(partial(compressed_psum, axis=axis, key=key),
+                       mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+                       check_vma=False)
     return fn(x)

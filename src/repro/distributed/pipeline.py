@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _pipeline_local(stage_fn: Callable, params_local, x_local, *,
@@ -55,11 +54,11 @@ def pipeline_forward(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
     (sharded over ``axis``); x [n_micro, mb, ...] (replicated over
     ``axis``).  Returns y [n_micro, mb, ...] replicated over ``axis``."""
     pspec = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_pipeline_local, stage_fn, axis=axis, n_micro=n_micro),
         mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)

@@ -8,7 +8,7 @@ import os
 
 import jax
 
-from .checksum import tensor_checksum_pallas
+from .checksum import hash_rows_pallas, tensor_checksum_pallas
 from .ref import tensor_checksum as tensor_checksum_ref
 from .ref import tree_checksums as tree_checksums_ref
 
@@ -39,8 +39,8 @@ def tensor_checksum_batch(mat, use_pallas=None):
 
     Off-TPU the blockwise evaluation runs directly in NumPy on the host
     (uint32 multiply-add wraps mod 2^32, integer-identical to the jnp
-    oracle and the Pallas kernel — tests assert ==); on TPU rows route
-    through the Pallas kernel.
+    oracle and the Pallas kernel — tests assert ==); on TPU the whole
+    matrix goes through the Pallas kernel in one call.
     """
     import numpy as np
 
@@ -50,17 +50,9 @@ def tensor_checksum_batch(mat, use_pallas=None):
     rows, n = mat.shape
     if rows == 0 or n == 0:
         return np.zeros((rows,), np.uint32)
-    # The Pallas route is currently per-row (a vmapped batch kernel is
-    # future work), so it only makes sense on real TPU hardware or when
-    # explicitly requested — REPRO_USE_PALLAS=1 alone (CPU interpret
-    # emulation) must not turn the recovery scan's one batched call back
-    # into n_records interpreted dispatches.
     on_tpu = jax.default_backend() == "tpu"
     if use_pallas or (use_pallas is None and on_tpu):
-        import jax.numpy as jnp
-        return jnp.stack([tensor_checksum_pallas(jnp.asarray(row),
-                                                 interpret=not on_tpu)
-                          for row in mat])
+        return hash_rows_pallas(mat, interpret=not on_tpu)
     from .ref import _BLOCK, _R_BLOCK, powers
     if n <= _BLOCK:
         return (mat * powers(n)[None, :]).sum(axis=1, dtype=np.uint32)

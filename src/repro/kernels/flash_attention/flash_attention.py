@@ -94,7 +94,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            cap: Optional[float] = None,
                            scale: Optional[float] = None,
                            bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q [B,H,S,D]; k,v [B,KV,S,D] -> [B,H,S,D]."""
     B, H, S, D = q.shape
     KV = k.shape[1]

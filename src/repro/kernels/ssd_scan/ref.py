@@ -49,10 +49,12 @@ def ssd_reference(xh: jax.Array, dt: jax.Array, A_log: jax.Array,
     xdt = xc * dtc[..., None]                                 # [B,nc,Q,H,P]
 
     cum = jnp.cumsum(ac, axis=2)                              # A_i (inclusive)
-    # intra-chunk: L[i,j] = exp(A_i - A_j), j <= i
+    # intra-chunk: L[i,j] = exp(A_i - A_j), j <= i.  Mask before the
+    # exp: above the diagonal A_i - A_j > 0 overflows to inf over a long
+    # chunk, and inf under a masked where makes a NaN gradient.
     seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]       # [B,nc,i,j,H]
     tri = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.where(tri[None, None, :, :, None], jnp.exp(seg), 0.0)
+    L = jnp.exp(jnp.where(tri[None, None, :, :, None], seg, -jnp.inf))
     s = jnp.einsum("bcihn,bcjhn->bchij", Crep, Brep)
     w = s * jnp.transpose(L, (0, 1, 4, 2, 3))                 # [B,nc,H,i,j]
     y_intra = jnp.einsum("bchij,bcjhp->bcihp", w, xdt)

@@ -436,7 +436,6 @@ def _moe_ffn_ep(x, p, cfg: ModelConfig):
     (data, model) grid — each rank owns E/R experts and T/R tokens.
     Returns (y, aux)."""
     import math as _math
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, axes = _EP_STATE
@@ -498,9 +497,9 @@ def _moe_ffn_ep(x, p, cfg: ModelConfig):
         y = jnp.zeros((T_loc, D), xt.dtype).at[tok].add(vals)
         return y.reshape(xl.shape)
 
-    y = shard_map(body, mesh=mesh,
-                  in_specs=(spec4, spec4, spec4, spec_wi, spec_wo),
-                  out_specs=spec4, check_rep=False)(
+    y = jax.shard_map(body, mesh=mesh,
+                      in_specs=(spec4, spec4, spec4, spec_wi, spec_wo),
+                      out_specs=spec4, check_vma=False)(
         xg, gig, gag, p["experts"]["wi"], p["experts"]["wo"])
     y = y.reshape(B, S, D)
     if cfg.n_shared_experts:

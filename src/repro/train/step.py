@@ -58,7 +58,7 @@ def train_step(state, batch, *, cfg: ModelConfig, opt_cfg: OptConfig,
     if journal:
         # integrity primitive over the state delta (per-leaf hash of the
         # gradients); replicated output => cross-pod replication in HLO
-        metrics["integrity"] = cksum.tree_checksums(grads, use_pallas=False)
+        metrics["integrity"] = cksum.tree_checksums(grads)
     new_state = {"params": new_params, "opt": new_opt,
                  "step": state["step"] + 1}
     return new_state, metrics

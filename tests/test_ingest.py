@@ -51,8 +51,12 @@ def test_concurrent_producers_all_acked_and_recovered():
     eng = IngestEngine(log, IngestConfig())
     n_threads, per = 8, 50
     tickets = [[] for _ in range(n_threads)]
+    # all producers alive at once: a thread that starts after another
+    # has exited may reuse its ident and pass for the same producer
+    start = threading.Barrier(n_threads)
 
     def producer(tid):
+        start.wait(timeout=30)
         for p in _payloads(tid, per):
             tickets[tid].append(eng.append(p))
         for t in tickets[tid]:

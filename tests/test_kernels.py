@@ -74,6 +74,27 @@ def test_checksum_batch_matches_per_row(lanes):
     np.testing.assert_array_equal(pallas, per_row)
 
 
+@pytest.mark.parametrize("lanes", [2 * 32768, 3 * 32768 + 5, 5 * 32768 - 1])
+def test_checksum_kernel_spans_blocks(lanes):
+    """Rows of several 128 KiB kernel blocks: the int32 kernel with its
+    lane-dense partials still equals the oracle and the NumPy batch
+    path bit for bit (stored hash values must never change)."""
+    from repro.kernels.checksum.checksum import BLOCK, hash_rows_pallas
+    from repro.kernels.checksum.ops import tensor_checksum_batch
+    assert lanes > BLOCK
+    rng = np.random.default_rng(lanes)
+    mat = rng.integers(0, 2 ** 32, size=(3, lanes), dtype=np.uint32)
+    mat[1, lanes // 3:] = 0
+    host = np.asarray(tensor_checksum_batch(mat, use_pallas=False),
+                      np.uint32)
+    kernel_rows = hash_rows_pallas(mat, interpret=True)
+    per_row = [int(tensor_checksum_pallas(jnp.asarray(r), interpret=True))
+               for r in mat]
+    oracle = [int(tensor_checksum(jnp.asarray(r))) for r in mat]
+    np.testing.assert_array_equal(kernel_rows, host)
+    assert per_row == oracle == host.tolist()
+
+
 # --------------------------- flash attention --------------------------- #
 
 @pytest.mark.parametrize("B,H,KV,S,D", [
@@ -171,3 +192,54 @@ def test_ssd_bf16_inputs():
     np.testing.assert_allclose(np.asarray(y_k, np.float32),
                                np.asarray(y_ref, np.float32),
                                atol=5e-2, rtol=5e-2)
+
+
+@pytest.mark.parametrize("B,S,H,P,G,N,chunk", [
+    (2, 64, 4, 32, 2, 16, 16),
+    (1, 128, 2, 64, 1, 32, 32),
+])
+def test_ssd_pallas_grad_matches_reference(B, S, H, P, G, N, chunk):
+    """ssd_pallas's custom VJP == jax.grad through ssd_reference."""
+    rng = np.random.default_rng(B * S + H)
+    args = (jnp.asarray(rng.normal(size=(B, S, H, P)), jnp.float32),
+            jnp.asarray(rng.uniform(0.05, 0.9, size=(B, S, H)), jnp.float32),
+            jnp.asarray(rng.uniform(-1.0, 0.5, size=(H,)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, S, G, N)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, S, G, N)), jnp.float32))
+    wy = jnp.asarray(rng.normal(size=(B, S, H, P)), jnp.float32)
+    ws = jnp.asarray(rng.normal(size=(B, H, P, N)), jnp.float32)
+
+    def loss(fn):
+        def f(*a):
+            y, st = fn(*a)
+            return jnp.sum(y * wy) + jnp.sum(st * ws)
+        return jax.grad(f, argnums=(0, 1, 2, 3, 4))
+
+    g_k = loss(lambda *a: ssd_pallas(*a, chunk=chunk, interpret=True))(*args)
+    g_r = loss(lambda *a: ssd_reference(*a, chunk=chunk))(*args)
+    for a, b in zip(g_k, g_r):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+def test_ssd_grad_finite_over_long_chunks():
+    """Strong decays over a 256-token chunk push A_i - A_j far past the
+    float32 exp range above the diagonal; the masked terms must not turn
+    the gradient into NaN (kernel VJP and reference alike)."""
+    rng = np.random.default_rng(11)
+    B, S, H, P, G, N, chunk = 1, 256, 2, 16, 1, 16, 256
+    args = (jnp.asarray(rng.normal(size=(B, S, H, P)), jnp.float32),
+            jnp.asarray(rng.uniform(1.0, 2.0, size=(B, S, H)), jnp.float32),
+            jnp.asarray(rng.uniform(0.0, 0.5, size=(H,)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, S, G, N)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, S, G, N)), jnp.float32))
+
+    def grads(fn):
+        def f(*a):
+            y, st = fn(*a)
+            return jnp.sum(y ** 2) + jnp.sum(st)
+        return jax.grad(f, argnums=(0, 1, 2, 3, 4))(*args)
+
+    for fn in (lambda *a: ssd_reference(*a, chunk=chunk),
+               lambda *a: ssd_pallas(*a, chunk=chunk, interpret=True)):
+        for g in grads(fn):
+            assert np.all(np.isfinite(np.asarray(g)))
