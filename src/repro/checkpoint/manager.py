@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.log import Log, LogFullError
 from .codec import (ShardCorruptError, ShardMeta, decode_shard, encode_shard,
                     shard_checksum)
@@ -87,6 +88,11 @@ class CheckpointManager:
         the paper's transaction-commit use case); otherwise the configured
         frequency policy amortizes the force.
         """
+        with obs.span(obs.CKPT_WRITE):
+            return self._save(step, state, extra, sync)
+
+    def _save(self, step: int, state, extra: Optional[Dict[str, Any]],
+              sync: bool) -> int:
         leaves = _leaf_paths(state)
         entries: List[Dict[str, Any]] = []
         futs = []
@@ -126,7 +132,8 @@ class CheckpointManager:
         save worker serializes saves, so manifests commit in step order
         (the log's in-order-commit invariant extended to checkpoints);
         shard writes within each save still fan out over _pool."""
-        state = _snapshot(state)
+        with obs.span(obs.CKPT_SNAPSHOT):
+            state = _snapshot(state)
         fut = self._save_pool.submit(self.save, step, state, extra)
         self._async.append(fut)
         return fut

@@ -64,6 +64,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Deque, Dict, Iterator, List, Optional, Sequence
 
+from .. import obs
 from .force_policy import ForcePolicy, SyncPolicy
 from .log import Log, LogError
 
@@ -208,6 +209,8 @@ class IngestEngine:
         self.shed = 0             # shed-deadline refusals
         self.direct = 0           # fast-path records (no collector hop)
         self.waves = 0            # batches the collector committed
+        self.collected = 0        # tickets the collector took into waves
+        self.queue_wait_s = 0.0   # their summed submit-to-collection wait
         self.forced_slices = 0
         self.max_wave_records = 0
         self.peak_queue_records = 0
@@ -271,19 +274,22 @@ class IngestEngine:
                     token = object()
                     self._shed_fifo.append(token)
                     try:
-                        ok = self._space.wait_for(
-                            lambda: self._closed
-                            or (self._shed_fifo[0] is token
-                                and self._fits_locked(t.size)),
-                            timeout=cfg.shed_deadline_s)
+                        with obs.span(obs.INGEST_ADMIT):
+                            ok = self._space.wait_for(
+                                lambda: self._closed
+                                or (self._shed_fifo[0] is token
+                                    and self._fits_locked(t.size)),
+                                timeout=cfg.shed_deadline_s)
                     finally:
                         self._shed_fifo.remove(token)
                         # head turn passes on (admitted or timed out):
                         # wake the next waiter to claim it
                         self._space.notify_all()
                 else:
-                    ok = self._space.wait_for(
-                        lambda: self._fits_locked(t.size), timeout=timeout)
+                    with obs.span(obs.INGEST_ADMIT):
+                        ok = self._space.wait_for(
+                            lambda: self._fits_locked(t.size),
+                            timeout=timeout)
                 if self._closed:
                     raise IngestClosedError(
                         "ingest engine closed during admission")
@@ -380,7 +386,11 @@ class IngestEngine:
                 self._queue.clear()
                 self._flush_asap = False
                 self._collecting = True
-            self._ingest_wave(tickets)
+                now = time.monotonic()
+                self.collected += len(tickets)
+                self.queue_wait_s += sum(now - t.t_submit for t in tickets)
+            with obs.span(obs.INGEST_WAVE):
+                self._ingest_wave(tickets)
 
     def _ingest_wave(self, tickets: List[IngestTicket]) -> None:
         log = self.log
@@ -465,14 +475,15 @@ class IngestEngine:
             if not self._unacked or self._unacked[0].lsn is None \
                     or self._unacked[0].lsn > d:
                 return
-            ready: List[IngestTicket] = []
-            while self._unacked and self._unacked[0].lsn is not None \
-                    and self._unacked[0].lsn <= d:
-                ready.append(self._unacked.popleft())
-            stamps = log.durable_ack_times([t.lsn for t in ready])
-            for t, ts in zip(ready, stamps):
-                self._resolve_locked(t, t_ack=ts)
-            self._resolved.notify_all()
+            with obs.span(obs.INGEST_ACK):
+                ready: List[IngestTicket] = []
+                while self._unacked and self._unacked[0].lsn is not None \
+                        and self._unacked[0].lsn <= d:
+                    ready.append(self._unacked.popleft())
+                stamps = log.durable_ack_times([t.lsn for t in ready])
+                for t, ts in zip(ready, stamps):
+                    self._resolve_locked(t, t_ack=ts)
+                self._resolved.notify_all()
 
     def _fail_unacked(self, exc: BaseException) -> None:
         """A force/drain failure: ack every LSN-assigned ticket the
@@ -613,16 +624,13 @@ class IngestEngine:
         with self._lock:
             return list(self._lat)
 
-    def latency_percentiles(self, pcts: Sequence[float] = (50.0, 99.0, 99.9)
-                            ) -> Dict[str, float]:
-        return latency_percentiles(self.latencies(), pcts)
-
     def stats(self) -> dict:
         with self._lock:
             return dict(submitted=self.submitted, acked=self.acked,
                         failed=self.failed, rejected=self.rejected,
                         shed=self.shed, direct=self.direct,
-                        waves=self.waves,
+                        waves=self.waves, collected=self.collected,
+                        queue_wait_s=self.queue_wait_s,
                         forced_slices=self.forced_slices,
                         max_wave_records=self.max_wave_records,
                         peak_queue_records=self.peak_queue_records,

@@ -54,6 +54,7 @@ from typing import Deque, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from .pmem import PMEMDevice
 from .primitives import (AtomicRegion, ForceRound, REP_LF, reissue_segs,
                          write_and_force, write_and_force_segs_async)
@@ -206,11 +207,14 @@ def _rec_phash(lsn: int, size: int, payload) -> int:
     the recovery scan's batched validator builds (_first_bad_payload).
     """
     from ..kernels.checksum.ops import tensor_checksum_batch
-    row = np.zeros((1, (_SEED.size + size + 3) // 4), dtype=np.uint32)
-    row_u8 = row.view(np.uint8)
-    row_u8[0, :_SEED.size] = np.frombuffer(_SEED.pack(lsn, size), np.uint8)
-    row_u8[0, _SEED.size:_SEED.size + size] = np.frombuffer(payload, np.uint8)
-    return int(tensor_checksum_batch(row)[0])
+    with obs.span(obs.LOG_HASH):
+        row = np.zeros((1, (_SEED.size + size + 3) // 4), dtype=np.uint32)
+        row_u8 = row.view(np.uint8)
+        row_u8[0, :_SEED.size] = np.frombuffer(_SEED.pack(lsn, size),
+                                               np.uint8)
+        row_u8[0, _SEED.size:_SEED.size + size] = np.frombuffer(payload,
+                                                                np.uint8)
+        return int(tensor_checksum_batch(row)[0])
 
 
 def _rec_checksum(lsn: int, size: int, payload, phash: bool) -> int:
@@ -342,23 +346,8 @@ def _first_bad_payload(raw: bytes, items) -> Optional[int]:
         ph_items = [it for it in ph_items if it[0] < bad]
     if ph_items:
         from ..kernels.checksum.ops import tensor_checksum_batch
-        snap = np.frombuffer(raw, dtype=np.uint8)
-        cap = snap.size
-        sizes = np.array([min(it[3], max(cap - it[1] - REC_HDR_SIZE, 0))
-                          for it in ph_items], dtype=np.int64)
-        lanes = 3 + (int(sizes.max()) + 3) // 4
-        mat = np.zeros((len(ph_items), lanes), dtype=np.uint32)
-        rows_u8 = mat.view(np.uint8)
-        for j, (i, pos, lsn, size, crc, _) in enumerate(ph_items):
-            n = int(sizes[j])
-            p0 = pos + REC_HDR_SIZE
-            rows_u8[j, _SEED.size:_SEED.size + n] = snap[p0:p0 + n]
-        lsns = np.array([it[2] for it in ph_items], dtype=np.uint64)
-        mat[:, 0] = (lsns & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-        mat[:, 1] = (lsns >> np.uint64(32)).astype(np.uint32)
-        # hash covers the *claimed* size (clamped rows fail the compare)
-        mat[:, 2] = np.array([it[3] & 0xFFFFFFFF for it in ph_items],
-                             dtype=np.uint32)
+        with obs.span(obs.OPEN_LANES):
+            mat = _lane_matrix(raw, ph_items)
         vals = np.asarray(tensor_checksum_batch(mat), dtype=np.uint32)
         crcs = np.array([it[4] & 0xFFFFFFFF for it in ph_items],
                         dtype=np.uint32)
@@ -367,6 +356,29 @@ def _first_bad_payload(raw: bytes, items) -> Optional[int]:
             b = ph_items[int(fails[0])][0]
             bad = b if bad is None else min(bad, b)
     return bad
+
+
+def _lane_matrix(raw: bytes, ph_items) -> np.ndarray:
+    """One zero-padded (seed || payload) lane row per FLAG_PHASH item,
+    the layout ``_rec_phash`` hashes at append."""
+    snap = np.frombuffer(raw, dtype=np.uint8)
+    cap = snap.size
+    sizes = np.array([min(it[3], max(cap - it[1] - REC_HDR_SIZE, 0))
+                      for it in ph_items], dtype=np.int64)
+    lanes = 3 + (int(sizes.max()) + 3) // 4
+    mat = np.zeros((len(ph_items), lanes), dtype=np.uint32)
+    rows_u8 = mat.view(np.uint8)
+    for j, (i, pos, lsn, size, crc, _) in enumerate(ph_items):
+        n = int(sizes[j])
+        p0 = pos + REC_HDR_SIZE
+        rows_u8[j, _SEED.size:_SEED.size + n] = snap[p0:p0 + n]
+    lsns = np.array([it[2] for it in ph_items], dtype=np.uint64)
+    mat[:, 0] = (lsns & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    mat[:, 1] = (lsns >> np.uint64(32)).astype(np.uint32)
+    # hash covers the *claimed* size (clamped rows fail the compare)
+    mat[:, 2] = np.array([it[3] & 0xFFFFFFFF for it in ph_items],
+                         dtype=np.uint32)
+    return mat
 
 
 class AckRateEstimator:
@@ -603,6 +615,8 @@ class Log:
         self.full_reclaims = 0        # LogFullError last-ditch reclaims
         self.trimmed_records_total = 0
         self.trimmed_bytes_total = 0
+        self.rounds_retired = 0       # durability rounds retired
+        self.round_wall_s = 0.0       # their summed issue-to-retire seconds
         self.force_vns_total = 0.0    # accumulated modelled hardware WORK
         # virtual-timeline modelled TIME (DESIGN.md §14): retired rounds
         # are placed on per-resource clocks (cpu / flush / wire:<id>),
@@ -643,8 +657,9 @@ class Log:
     def open(cls, dev: PMEMDevice, cfg: LogConfig,
              repl: Optional[ReplicationGroup] = None) -> "Log":
         """Local (single-copy) recovery: §4.3 Recovery Iterator."""
-        log = cls(dev, cfg, repl)
-        log._recover_local()
+        with obs.span(obs.OPEN):
+            log = cls(dev, cfg, repl)
+            log._recover_local()
         return log
 
     def _write_superline(self) -> float:
@@ -687,25 +702,26 @@ class Log:
         for fidelity with Table 2).  The pointer is None in strict device
         mode; use copy() then.
         """
-        if size < 0 or _align8(REC_HDR_SIZE + size) > self.cfg.capacity:
-            raise ValueError("bad record size")
-        try:
-            with self._alloc_lock:
-                lsn, rec, fire = self._reserve_locked(size)
-        except LogFullError:
-            # graceful degradation (DESIGN.md §13): give the lifecycle
-            # callback one shot at checkpoint+trim, then retry once
-            if not self._reclaim_on_full():
-                raise
-            with self._alloc_lock:
-                lsn, rec, fire = self._reserve_locked(size)
-        if fire:
-            # defer to complete(): firing here would run the callback
-            # while THIS record is reserved-but-uncompleted, and a sync
-            # checkpoint save inside it would wait forever on in-order
-            # commit past the hole
-            self._space_low_pending = True
-        return lsn, self.dev.view(rec.off + REC_HDR_SIZE, size)
+        with obs.span(obs.LOG_RESERVE):
+            if size < 0 or _align8(REC_HDR_SIZE + size) > self.cfg.capacity:
+                raise ValueError("bad record size")
+            try:
+                with self._alloc_lock:
+                    lsn, rec, fire = self._reserve_locked(size)
+            except LogFullError:
+                # graceful degradation (DESIGN.md §13): give the lifecycle
+                # callback one shot at checkpoint+trim, then retry once
+                if not self._reclaim_on_full():
+                    raise
+                with self._alloc_lock:
+                    lsn, rec, fire = self._reserve_locked(size)
+            if fire:
+                # defer to complete(): firing here would run the callback
+                # while THIS record is reserved-but-uncompleted, and a sync
+                # checkpoint save inside it would wait forever on in-order
+                # commit past the hole
+                self._space_low_pending = True
+            return lsn, self.dev.view(rec.off + REC_HDR_SIZE, size)
 
     def _reserve_locked(self, size: int) -> Tuple[int, "_Rec", bool]:
         off, pad_room = self._fit(size)
@@ -811,10 +827,11 @@ class Log:
     def copy(self, rec_id: int, data: bytes, at: int = 0) -> float:
         """Concurrent: copy payload bytes into the reserved record
         (non-temporal-store path)."""
-        rec = self._recs[rec_id]
-        if at + len(data) > rec.size:
-            raise ValueError("copy out of record bounds")
-        return self.dev.write(rec.off + REC_HDR_SIZE + at, data)
+        with obs.span(obs.LOG_COPY):
+            rec = self._recs[rec_id]
+            if at + len(data) > rec.size:
+                raise ValueError("copy out of record bounds")
+            return self.dev.write(rec.off + REC_HDR_SIZE + at, data)
 
     def _use_phash(self, size: int) -> bool:
         t = self.cfg.phash_threshold
@@ -822,26 +839,27 @@ class Log:
 
     def complete(self, rec_id: int) -> float:
         """Concurrent: checksum the payload and publish the valid header."""
-        rec = self._recs[rec_id]
-        view = self.dev.view(rec.off + REC_HDR_SIZE, rec.size)
-        payload = view if view is not None else self.dev.read(
-            rec.off + REC_HDR_SIZE, rec.size)
-        phash = self._use_phash(rec.size)
-        crc = _rec_checksum(rec.lsn, rec.size, payload, phash)
-        flags = FLAG_VALID | (FLAG_PHASH if phash else 0)
-        vns = self.dev.write(
-            rec.off, _REC_HDR.pack(rec.lsn, rec.size, crc, flags))
-        vns += self.dev.cost.crc_byte_ns * rec.size
-        self._mark_complete(rec_id)
-        if self._space_low_pending:
-            # the crossing record is committed now, so a sync
-            # checkpoint inside the callback can force its manifest
-            # without waiting on a reservation hole (benign race on
-            # the flag: the guard is non-reentrant and the latch
-            # stops refires)
-            self._space_low_pending = False
-            self._fire_space_low()
-        return vns
+        with obs.span(obs.LOG_COMPLETE):
+            rec = self._recs[rec_id]
+            view = self.dev.view(rec.off + REC_HDR_SIZE, rec.size)
+            payload = view if view is not None else self.dev.read(
+                rec.off + REC_HDR_SIZE, rec.size)
+            phash = self._use_phash(rec.size)
+            crc = _rec_checksum(rec.lsn, rec.size, payload, phash)
+            flags = FLAG_VALID | (FLAG_PHASH if phash else 0)
+            vns = self.dev.write(
+                rec.off, _REC_HDR.pack(rec.lsn, rec.size, crc, flags))
+            vns += self.dev.cost.crc_byte_ns * rec.size
+            self._mark_complete(rec_id)
+            if self._space_low_pending:
+                # the crossing record is committed now, so a sync
+                # checkpoint inside the callback can force its manifest
+                # without waiting on a reservation hole (benign race on
+                # the flag: the guard is non-reentrant and the latch
+                # stops refires)
+                self._space_low_pending = False
+                self._fire_space_low()
+            return vns
 
     def _mark_complete(self, rec_id: int) -> None:
         with self._commit_cv:
@@ -873,13 +891,6 @@ class Log:
             self._commit_cv.notify_all()
 
     # -- force: the pipelined force engine (DESIGN.md §8-9) --------------- #
-    @property
-    def _force_busy(self) -> bool:
-        """True when no further round can be issued right now (pipeline
-        full).  Kept for introspection; the pre-PR4 serial engine exposed
-        the same flag for its single critical section."""
-        return len(self._inflight) >= self._depth
-
     @property
     def pipeline_depth(self) -> int:
         """The effective in-flight round limit right now: the adaptive
@@ -1128,7 +1139,7 @@ class Log:
                 return None
             if self._issue_lsn >= lsn:
                 return self._covering_round_locked(lsn)
-        with self._issue_lock:
+        with self._issue_lock, obs.span(obs.LOG_ISSUE) as issue_span:
             salvage: Optional[List[_SalvageSeg]] = None
             with self._commit_cv:
                 if self._durable_lsn >= lsn:
@@ -1186,6 +1197,7 @@ class Log:
                 # k < depth, so that round has retired)
                 rel = len(self._vt_tail) + len(self._inflight) - self._depth
                 entry.vt_after = self._vt_tail[rel] if rel >= 0 else 0.0
+                issue_span.set_metadata(round=entry.end_lsn)
                 self._inflight.append(entry)
                 self._issue_lsn = entry.end_lsn
                 self._issue_off = entry.end_off % self.cfg.capacity
@@ -1207,7 +1219,8 @@ class Log:
                     handle = write_and_force_segs_async(
                         self.dev, self._range_segs(start_off, end_off),
                         self.repl, self.cfg.ordering,
-                        local_durable=self.cfg.local_durable)
+                        local_durable=self.cfg.local_durable,
+                        round_lsn=entry.end_lsn)
             except BaseException as exc:
                 with self._commit_cv:
                     # surfaced=True: the issuing leader raises it itself
@@ -1239,33 +1252,36 @@ class Log:
                     # permanently failed round (PR 10 satellite)
                     self._pipe_fail_locked(entry, exc)
                     break
-                self._inflight.popleft()
-                now = time.monotonic()
-                self._durable_lsn = entry.end_lsn
-                self._durable_off = entry.end_off % self.cfg.capacity
-                self.force_vns_total += vns
-                # place the round on the virtual timeline: its modelled
-                # completion is the max over its resource intervals, not
-                # the scalar sum — overlapped rounds now overlap in
-                # modelled time (DESIGN.md §14)
-                vt_end = entry.handle.schedule_on(self.timeline,
-                                                  entry.vt_after)
-                if vt_end > self._durable_vtime:
-                    self._durable_vtime = vt_end
-                self._vt_tail.append(vt_end)
-                self._clean_retires += 1
-                self._ack_est.observe_retire(now, entry.issued_at)
-                self._record_ack_locked(entry.end_lsn, now, vns, vt_end)
-                if entry.salvage_src:
-                    # the salvaged ranges reached their write quorum after
-                    # all: durability was achieved, so the failures that
-                    # were deferred with no covering waiter are moot
-                    for seg in entry.salvage_src:
-                        for exc in seg.deferred:
-                            try:
-                                self._pipe_errors.remove(exc)
-                            except ValueError:
-                                pass
+                with obs.span(obs.LOG_RETIRE, round=entry.end_lsn):
+                    self._inflight.popleft()
+                    now = time.monotonic()
+                    self._durable_lsn = entry.end_lsn
+                    self._durable_off = entry.end_off % self.cfg.capacity
+                    self.force_vns_total += vns
+                    # place the round on the virtual timeline: its modelled
+                    # completion is the max over its resource intervals, not
+                    # the scalar sum — overlapped rounds now overlap in
+                    # modelled time (DESIGN.md §14)
+                    vt_end = entry.handle.schedule_on(self.timeline,
+                                                      entry.vt_after)
+                    if vt_end > self._durable_vtime:
+                        self._durable_vtime = vt_end
+                    self._vt_tail.append(vt_end)
+                    self._clean_retires += 1
+                    self.rounds_retired += 1
+                    self.round_wall_s += now - entry.issued_at
+                    self._ack_est.observe_retire(now, entry.issued_at)
+                    self._record_ack_locked(entry.end_lsn, now, vns, vt_end)
+                    if entry.salvage_src:
+                        # the salvaged ranges reached their write quorum after
+                        # all: durability was achieved, so the failures that
+                        # were deferred with no covering waiter are moot
+                        for seg in entry.salvage_src:
+                            for exc in seg.deferred:
+                                try:
+                                    self._pipe_errors.remove(exc)
+                                except ValueError:
+                                    pass
             self._commit_cv.notify_all()
 
     def _pipe_fail_locked(self, entry: _PipeRound, exc: BaseException,
@@ -1568,22 +1584,23 @@ class Log:
         for size in sizes:
             if size < 0 or _align8(REC_HDR_SIZE + size) > self.cfg.capacity:
                 raise ValueError("bad record size")
-        batch = Batch(lsns=[], sizes=list(sizes))
-        if not sizes:
+        with obs.span(obs.LOG_RESERVE):
+            batch = Batch(lsns=[], sizes=list(sizes))
+            if not sizes:
+                return batch
+            try:
+                with self._alloc_lock:
+                    fire = self._reserve_batch_locked(sizes, batch)
+            except LogFullError:
+                # the plan phase is pure, so the failed attempt left no
+                # partial state: run the lifecycle reclaim and retry once
+                if not self._reclaim_on_full():
+                    raise
+                with self._alloc_lock:
+                    fire = self._reserve_batch_locked(sizes, batch)
+            if fire:
+                self._space_low_pending = True    # fired at complete_batch
             return batch
-        try:
-            with self._alloc_lock:
-                fire = self._reserve_batch_locked(sizes, batch)
-        except LogFullError:
-            # the plan phase is pure, so the failed attempt left no
-            # partial state: run the lifecycle reclaim and retry once
-            if not self._reclaim_on_full():
-                raise
-            with self._alloc_lock:
-                fire = self._reserve_batch_locked(sizes, batch)
-        if fire:
-            self._space_low_pending = True    # fired at complete_batch
-        return batch
 
     def _reserve_batch_locked(self, sizes: List[int], batch: Batch) -> bool:
         # plan (pure): mirror _fit over a shadow tail
@@ -1648,49 +1665,51 @@ class Log:
 
     def copy_batch(self, batch: Batch, payloads: List[bytes]) -> float:
         """Concurrent: stage all payload bytes (ntstore cost model)."""
-        if len(payloads) != len(batch.lsns):
-            raise ValueError(
-                f"batch holds {len(batch.lsns)} records, got "
-                f"{len(payloads)} payloads")
-        total = 0
-        for i, data in enumerate(payloads):
-            rec, seg_idx, pay_off = batch._items[i]
-            if len(data) > rec.size:
-                raise ValueError("copy out of record bounds")
-            buf = batch._segs[seg_idx].buf
-            buf[pay_off : pay_off + len(data)] = data
-            total += len(data)
-        return self.dev.cost.store_byte_ns * total
+        with obs.span(obs.LOG_COPY):
+            if len(payloads) != len(batch.lsns):
+                raise ValueError(
+                    f"batch holds {len(batch.lsns)} records, got "
+                    f"{len(payloads)} payloads")
+            total = 0
+            for i, data in enumerate(payloads):
+                rec, seg_idx, pay_off = batch._items[i]
+                if len(data) > rec.size:
+                    raise ValueError("copy out of record bounds")
+                buf = batch._segs[seg_idx].buf
+                buf[pay_off : pay_off + len(data)] = data
+                total += len(data)
+            return self.dev.cost.store_byte_ns * total
 
     def complete_batch(self, batch: Batch) -> float:
         """Concurrent: checksum every payload in one sweep, pack all
         headers, publish each staged segment with ONE device write, and
         advance the complete watermark with ONE _commit_cv pass."""
-        if batch._completed:
-            raise LogError("batch already completed")
-        batch._completed = True
-        vns = 0.0
-        crc_bytes = 0
-        views = [memoryview(seg.buf) for seg in batch._segs]
-        pack, threshold = _REC_HDR.pack, self.cfg.phash_threshold
-        for rec, seg_idx, pay_off in batch._items:
-            mv = views[seg_idx]
-            size = rec.size
-            payload = mv[pay_off : pay_off + size]
-            phash = threshold is not None and size >= threshold
-            crc = _rec_checksum(rec.lsn, size, payload, phash)
-            flags = FLAG_VALID | (FLAG_PHASH if phash else 0)
-            mv[pay_off - REC_HDR_SIZE : pay_off] = pack(
-                rec.lsn, size, crc, flags)
-            crc_bytes += size
-        for seg in batch._segs:
-            vns += self.dev.write(self._abs(seg.ring_off), seg.buf)
-        vns += self.dev.cost.crc_byte_ns * crc_bytes
-        self._mark_complete_many(batch._pad_lsns + batch.lsns)
-        if self._space_low_pending:
-            self._space_low_pending = False
-            self._fire_space_low()
-        return vns
+        with obs.span(obs.LOG_COMPLETE):
+            if batch._completed:
+                raise LogError("batch already completed")
+            batch._completed = True
+            vns = 0.0
+            crc_bytes = 0
+            views = [memoryview(seg.buf) for seg in batch._segs]
+            pack, threshold = _REC_HDR.pack, self.cfg.phash_threshold
+            for rec, seg_idx, pay_off in batch._items:
+                mv = views[seg_idx]
+                size = rec.size
+                payload = mv[pay_off : pay_off + size]
+                phash = threshold is not None and size >= threshold
+                crc = _rec_checksum(rec.lsn, size, payload, phash)
+                flags = FLAG_VALID | (FLAG_PHASH if phash else 0)
+                mv[pay_off - REC_HDR_SIZE : pay_off] = pack(
+                    rec.lsn, size, crc, flags)
+                crc_bytes += size
+            for seg in batch._segs:
+                vns += self.dev.write(self._abs(seg.ring_off), seg.buf)
+            vns += self.dev.cost.crc_byte_ns * crc_bytes
+            self._mark_complete_many(batch._pad_lsns + batch.lsns)
+            if self._space_low_pending:
+                self._space_low_pending = False
+                self._fire_space_low()
+            return vns
 
     def force_batch(self, batch: Batch, freq: int = 1,
                     timeout: Optional[float] = None,
@@ -2143,20 +2162,23 @@ class Log:
         # below _LSN_VEC_MIN walk sequentially first (their values can
         # collide with on-media flags words); the remainder goes through
         # the vectorized planner.
-        raw = self._ring_snapshot()
+        with obs.span(obs.OPEN_SNAPSHOT):
+            raw = self._ring_snapshot()
         lo = s.head_lsn
-        plan, handoff = self._walk_chain(raw, s.head_off, lo, 0,
-                                         stop_lsn=max(lo, _LSN_VEC_MIN))
-        recs, tail, used, next_lsn = (plan.recs, plan.tail, plan.used,
-                                      plan.next_lsn)
-        if handoff:
-            vec = None
-            if tail % 8 == 0:
-                vec = self._plan_scan_vectorized(raw, tail, next_lsn, used)
-            if vec is None:
-                vec, _ = self._walk_chain(raw, tail, next_lsn, used)
-            recs = recs + vec.recs
-            tail, used, next_lsn = vec.tail, vec.used, vec.next_lsn
+        with obs.span(obs.OPEN_PLAN):
+            plan, handoff = self._walk_chain(raw, s.head_off, lo, 0,
+                                             stop_lsn=max(lo, _LSN_VEC_MIN))
+            recs, tail, used, next_lsn = (plan.recs, plan.tail, plan.used,
+                                          plan.next_lsn)
+            if handoff:
+                vec = None
+                if tail % 8 == 0:
+                    vec = self._plan_scan_vectorized(raw, tail, next_lsn,
+                                                     used)
+                if vec is None:
+                    vec, _ = self._walk_chain(raw, tail, next_lsn, used)
+                recs = recs + vec.recs
+                tail, used, next_lsn = vec.tail, vec.used, vec.next_lsn
         # durable trim watermark (DESIGN.md §13): a valid slot the
         # header chain reaches marks everything at or below it as
         # checkpointed-and-dead — recovery adopts the post-trim view
@@ -2169,12 +2191,13 @@ class Log:
         trim = self.read_trim_watermark()
         adopt = trim is not None and trim >= lo and next_lsn > trim
         skip_upto = trim if adopt else lo - 1
-        bad = _first_bad_payload(
-            raw, ((k, r[0], lo + k, r[1], r[2], r[3])
-                  for k, r in enumerate(recs)
-                  if lo + k > skip_upto
-                  and r[3] & FLAG_VALID
-                  and not (r[3] & (FLAG_PAD | FLAG_CLEANED))))
+        with obs.span(obs.OPEN_VALIDATE):
+            bad = _first_bad_payload(
+                raw, ((k, r[0], lo + k, r[1], r[2], r[3])
+                      for k, r in enumerate(recs)
+                      if lo + k > skip_upto
+                      and r[3] & FLAG_VALID
+                      and not (r[3] & (FLAG_PAD | FLAG_CLEANED))))
         if bad is not None:
             tail, used, next_lsn = recs[bad][0], recs[bad][5], lo + bad
             recs = recs[:bad]
@@ -2295,5 +2318,7 @@ class Log:
                         salvage_spilled_bytes=self.salvage_spilled_bytes,
                         salvage_spilled_images=self.salvage_spilled_images,
                         depth_bdp=self._ack_est.bdp_rounds(),
+                        rounds_retired=self.rounds_retired,
+                        round_wall_s=self.round_wall_s,
                         force_vns_total=self.force_vns_total,
                         durable_vtime=self._durable_vtime)

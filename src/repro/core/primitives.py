@@ -23,6 +23,7 @@ import zlib
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence, Tuple
 
+from .. import obs
 from .pmem import CostModel, PMEMDevice
 from .timeline import VirtualTimeline
 from .transport import (QuorumError, QuorumRound, ReplicationGroup,
@@ -251,6 +252,7 @@ def write_and_force_segs_async(
     repl: Optional[ReplicationGroup] = None,
     ordering: str = REP_LF,
     local_durable: bool = True,
+    round_lsn: Optional[int] = None,
 ) -> ForceRound:
     """Issue-side half of the replication primitive: post the doorbell,
     run the (overlapping) local flush, and return a :class:`ForceRound`
@@ -263,13 +265,15 @@ def write_and_force_segs_async(
     live backups) the round is complete by the time this returns and
     ``wait()`` is free; the local flush sequence — and therefore the
     local DeviceStats — is identical to the synchronous primitive.
+    ``round_lsn`` labels the round's lane spans (trace only).
     """
     segs = [(off, n) for off, n in segs if n > 0]
 
     def _persist_all() -> float:
         if not local_durable:
             return 0.0
-        return sum(dev.persist(off, n) for off, n in segs)
+        with obs.span(obs.LOG_FLUSH):
+            return sum(dev.persist(off, n) for off, n in segs)
 
     if not segs:
         return ForceRound(None, 0.0, ordering=ordering)
@@ -283,13 +287,15 @@ def write_and_force_segs_async(
         return ForceRound(None, loc_vns, ordering=ordering)
 
     if ordering == REP_LF:
-        rnd = repl.replicate_batch_async(dev, segs, local_ack_vns=0.0)
+        rnd = repl.replicate_batch_async(dev, segs, local_ack_vns=0.0,
+                                         round_lsn=round_lsn)
         loc_vns = _persist_all()       # overlaps the wire time
         return ForceRound(rnd, loc_vns, issue_vns=dev.cost.doorbell_ns,
                           ordering=REP_LF)
     if ordering in (LF_REP, PARALLEL):
         loc_vns = _persist_all()
-        rnd = repl.replicate_batch_async(dev, segs, local_ack_vns=loc_vns)
+        rnd = repl.replicate_batch_async(dev, segs, local_ack_vns=loc_vns,
+                                         round_lsn=round_lsn)
         return ForceRound(rnd, loc_vns, issue_vns=dev.cost.doorbell_ns,
                           ordering=ordering)
     raise ValueError(f"unknown ordering {ordering!r}")

@@ -31,6 +31,7 @@ from concurrent.futures import FIRST_COMPLETED, Future, ThreadPoolExecutor, \
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence, Tuple
 
+from .. import obs
 from .pmem import CostModel, PMEMDevice
 from .timeline import VirtualTimeline
 
@@ -123,6 +124,7 @@ class _StagedWrite:
     total: int
     read_vns: float
     posted_at: float
+    round_lsn: Optional[int] = None   # the log round's end LSN, if known
 
 
 class Transport:
@@ -235,11 +237,12 @@ class Transport:
         datas: List[Tuple[int, bytes]] = []
         read_vns = 0.0
         total = 0
-        for off, n in segs:
-            data, vns = src_dev.dma_read(off, n)   # NIC DMA at post time
-            datas.append((off, data))
-            read_vns += vns
-            total += n
+        with obs.span(obs.REPL_POST):
+            for off, n in segs:
+                data, vns = src_dev.dma_read(off, n)   # NIC DMA at post time
+                datas.append((off, data))
+                read_vns += vns
+                total += n
         return _StagedWrite(datas, total, read_vns, time.monotonic())
 
     def write_imm_staged(self, staged: _StagedWrite) -> float:
@@ -256,8 +259,12 @@ class Transport:
             raise TransportError("transport closed")
         vns = staged.read_vns + self.cost.rdma_rtt_ns \
             + staged.total * self.cost.rdma_byte_ns
-        for off, data in staged.datas:
-            vns += self.server.handle_write_imm(off, data, self.primary_id)
+        meta = {} if staged.round_lsn is None \
+            else {"round": staged.round_lsn}
+        with obs.span(obs.REPL_LANE, **meta):
+            for off, data in staged.datas:
+                vns += self.server.handle_write_imm(off, data,
+                                                    self.primary_id)
         return vns
 
     def read(self, off: int, n: int) -> Tuple[bytes, float]:
@@ -700,7 +707,8 @@ class ReplicationGroup:
 
     def replicate_batch_async(self, src_dev: PMEMDevice,
                               segs: Sequence[Tuple[int, int]],
-                              local_ack_vns: Optional[float] = 0.0
+                              local_ack_vns: Optional[float] = 0.0,
+                              round_lsn: Optional[int] = None
                               ) -> QuorumRound:
         """Post one doorbell-batched replication round on every live lane
         and return immediately with a :class:`QuorumRound` handle.
@@ -712,6 +720,7 @@ class ReplicationGroup:
         persistence complete on the FIFO lanes in the background.  A
         transport that fails its admission gate at post time is evicted
         on the spot and counts as a failed replica for this round.
+        ``round_lsn`` labels the lane spans of the round (trace only).
         """
         segs = list(segs)
         self._raise_deferred()
@@ -725,6 +734,7 @@ class ReplicationGroup:
                 t.close()        # evict, exactly as the lane harvest would
                 rnd._note_unposted(t)
                 continue
+            staged.round_lsn = round_lsn
             rnd._set_occ(t, staged.read_vns
                          + staged.total * t.cost.rdma_byte_ns)
             fut = self._submit(t, lambda tt, s=staged: tt.write_imm_staged(s))

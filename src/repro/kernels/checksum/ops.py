@@ -8,6 +8,7 @@ import os
 
 import jax
 
+from ... import obs
 from .checksum import hash_rows_pallas, tensor_checksum_pallas
 from .ref import tensor_checksum as tensor_checksum_ref
 from .ref import tree_checksums as tree_checksums_ref
@@ -50,10 +51,19 @@ def tensor_checksum_batch(mat, use_pallas=None):
     rows, n = mat.shape
     if rows == 0 or n == 0:
         return np.zeros((rows,), np.uint32)
-    on_tpu = jax.default_backend() == "tpu"
-    if use_pallas or (use_pallas is None and on_tpu):
-        return hash_rows_pallas(mat, interpret=not on_tpu)
+    with obs.span(obs.CHECKSUM_CALL):
+        on_tpu = jax.default_backend() == "tpu"
+        if use_pallas or (use_pallas is None and on_tpu):
+            return hash_rows_pallas(mat, interpret=not on_tpu)
+        return _hash_rows_numpy(mat)
+
+
+def _hash_rows_numpy(mat):
+    """The blockwise evaluation of ``tensor_checksum_batch`` in NumPy."""
+    import numpy as np
+
     from .ref import _BLOCK, _R_BLOCK, powers
+    rows, n = mat.shape
     if n <= _BLOCK:
         return (mat * powers(n)[None, :]).sum(axis=1, dtype=np.uint32)
     pad = (-n) % _BLOCK

@@ -29,6 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..checkpoint import CheckpointManager
 from ..data import SyntheticDataset
 from ..models.config import ModelConfig
@@ -100,16 +101,20 @@ class Trainer:
         end = min(self.tcfg.total_steps,
                   start + (n_steps or self.tcfg.total_steps))
         for s in range(start, end):
-            batch = {k: jnp.asarray(v)
-                     for k, v in self.data.batch_at(s).items()}
+            with obs.span(obs.TRAIN_BATCH):
+                batch = {k: jnp.asarray(v)
+                         for k, v in self.data.batch_at(s).items()}
             self.data.step = s + 1
-            self.state, metrics = self._step_fn(self.state, batch)
-            loss = float(metrics["loss"])
+            with obs.span(obs.TRAIN_STEP):
+                self.state, metrics = self._step_fn(self.state, batch)
+            with obs.span(obs.TRAIN_LOSS):
+                loss = float(metrics["loss"])
             self.report.losses.append(loss)
             self.report.steps_run += 1
             if s % self.tcfg.journal_every == 0:
-                self.mgr.journal({"step": s, "loss": loss},
-                                 sync=False)
+                with obs.span(obs.TRAIN_JOURNAL):
+                    self.mgr.journal({"step": s, "loss": loss},
+                                     sync=False)
             if (s + 1) % self.tcfg.ckpt_every == 0:
                 self._checkpoint(s + 1)
         # end-of-run: drain outstanding writes, force the journal

@@ -133,7 +133,7 @@ def test_force_exception_resets_pipeline_and_unblocks_later_forces():
         log.force(rid)
     dev.persist = orig
     assert log.stats()["inflight_rounds"] == 0
-    assert not log._force_busy
+    assert log.pipeline_free
     assert log.force(rid) == rid           # no deferred re-raise, no wedge
     assert log.durable_lsn == rid
 
