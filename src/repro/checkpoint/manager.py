@@ -89,6 +89,8 @@ class CheckpointManager:
         frequency policy amortizes the force.
         """
         with obs.span(obs.CKPT_WRITE):
+            with obs.span(obs.CKPT_FETCH):
+                state = _fetch(state)
             return self._save(step, state, extra, sync)
 
     def _save(self, step: int, state, extra: Optional[Dict[str, Any]],
@@ -275,6 +277,30 @@ class CheckpointManager:
 
 
 def _snapshot(tree):
-    """Deep-copy leaves to host so async saves see a stable image."""
+    """A stable image of ``tree`` for an async save, taken without
+    waiting for the device.
+
+    A ``jax.Array`` leaf is immutable: it is kept by reference and its
+    device-to-host copy is started, for every leaf before any is waited
+    on, so the copies overlap each other and the caller's next steps;
+    the save worker materialises them (``_fetch``).  The image is exact
+    only while the caller does not donate the arrays to a later
+    computation (``Trainer`` does not); a donated leaf would make the
+    fetch fail on a deleted array, not save wrong bytes.  Any other
+    leaf (NumPy) may be mutated in place by the caller, so it is
+    deep-copied here.
+    """
     import jax
-    return jax.tree_util.tree_map(lambda x: np.array(x), tree)
+
+    def snap(x):
+        if isinstance(x, jax.Array):
+            x.copy_to_host_async()
+            return x
+        return np.array(x)
+    return jax.tree_util.tree_map(snap, tree)
+
+
+def _fetch(tree):
+    """``tree`` with every ``jax.Array`` leaf as a host NumPy array."""
+    import jax
+    return jax.device_get(tree)

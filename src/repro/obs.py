@@ -57,8 +57,13 @@ TRAIN_LOSS = "arcadia.train.loss"         # waiting for the step's loss
 TRAIN_JOURNAL = "arcadia.train.journal"   # the step's journal record
 
 # checkpoint (checkpoint/manager.py)
-CKPT_SNAPSHOT = "arcadia.ckpt.snapshot"   # synchronous host copy of state
-CKPT_WRITE = "arcadia.ckpt.write"         # encode, store puts, manifest
+CKPT_SNAPSHOT = "arcadia.ckpt.snapshot"   # on the caller: start the state's
+                                          # device-to-host copies (NumPy
+                                          # leaves: the copy itself)
+CKPT_WRITE = "arcadia.ckpt.write"         # fetch, encode, store puts,
+                                          # manifest
+CKPT_FETCH = "arcadia.ckpt.fetch"         # waiting out the state's copies
+                                          # to the host, inside the write
 
 NAMES = frozenset(v for k, v in list(globals().items())
                   if k.isupper() and isinstance(v, str)

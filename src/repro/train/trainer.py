@@ -68,6 +68,9 @@ class Trainer:
         self.mgr = mgr
         self.tcfg = tcfg
         self.report = TrainerReport()
+        # no donate_argnums: a step leaves its input state intact, so an
+        # async save may hold the state's arrays by reference while later
+        # steps run (checkpoint.manager._snapshot)
         self._step_fn = jax.jit(make_train_step(cfg, opt_cfg))
         self._pending_save = None
         self.state = None

@@ -187,3 +187,60 @@ def test_save_async_overlaps():
     futs = [mgr.save_async(s, state) for s in (1, 2, 3)]
     mgr.wait()
     assert mgr.latest_step() == 3
+
+
+def test_save_async_of_device_arrays_is_the_image_at_its_step():
+    """Later updates make new arrays; the save keeps the ones it was
+    given, so it restores bit-equal to the state at its step."""
+    mgr, stores, log = make_mgr()
+    state = jax.tree_util.tree_map(jnp.asarray, make_state(1))
+    at_save = jax.device_get(state)
+    mgr.save_async(7, state)
+    for _ in range(3):
+        state = jax.tree_util.tree_map(lambda x: x * 2 + 1, state)
+    mgr.wait()
+    step, got, _ = mgr.restore(at_save)
+    assert step == 7
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(at_save)):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
+def test_save_async_of_numpy_leaves_ignores_later_mutation():
+    mgr, stores, log = make_mgr()
+    state = make_state(2)
+    before = jax.tree_util.tree_map(np.copy, state)
+    mgr.save_async(3, state)
+    state["params"]["embed"][:] = 0.0
+    state["opt"]["mu"] += 1.0
+    mgr.wait()
+    _, got, _ = mgr.restore(before)
+    assert_tree_equal(got, before)
+
+
+def test_save_async_leaves_the_host_copy_to_the_save_worker(monkeypatch):
+    """The caller only starts the copies: the snapshot holds the device
+    arrays themselves, and the save worker fetches them."""
+    import threading
+    from repro.checkpoint import manager as mgr_mod
+    fetched_on = []
+    fetch = mgr_mod._fetch
+
+    def recording_fetch(tree):
+        fetched_on.append(threading.current_thread().name)
+        return fetch(tree)
+
+    monkeypatch.setattr(mgr_mod, "_fetch", recording_fetch)
+    state = jax.tree_util.tree_map(jnp.asarray, make_state(3))
+    snap = mgr_mod._snapshot(state)
+    for a, b in zip(jax.tree_util.tree_leaves(snap),
+                    jax.tree_util.tree_leaves(state)):
+        assert isinstance(a, jax.Array) and a is b
+    mgr, stores, log = make_mgr()
+    mgr.save_async(4, state)
+    mgr.wait()
+    assert len(fetched_on) == 1
+    assert fetched_on[0].startswith("ckpt-save")
+    assert fetched_on[0] != threading.current_thread().name
+    _, got, _ = mgr.restore(state)
+    assert_tree_equal(got, state)

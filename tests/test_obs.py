@@ -179,7 +179,7 @@ def test_every_listed_name_is_emitted_and_no_other(traced):
     names = {s.name for s in spans}
     assert names <= obs.NAMES, names - obs.NAMES
     assert names == obs.NAMES, obs.NAMES - names
-    assert len(obs.NAMES) == 24
+    assert len(obs.NAMES) == 25
 
 
 @pytest.mark.parametrize("child,parent", [
@@ -198,6 +198,7 @@ def test_every_listed_name_is_emitted_and_no_other(traced):
     (obs.CHECKSUM_CALL, obs.OPEN_VALIDATE),
     (obs.LOG_RESERVE, obs.TRAIN_JOURNAL),
     (obs.LOG_COMPLETE, obs.CKPT_WRITE),
+    (obs.CKPT_FETCH, obs.CKPT_WRITE),
 ])
 def test_spans_nest_as_the_layers_do(traced, child, parent):
     _, spans, _ = traced
@@ -288,10 +289,14 @@ def test_trainer_spans_per_step(traced):
                  obs.TRAIN_JOURNAL):
         assert count[name] == 2, name
     assert count[obs.CKPT_SNAPSHOT] == 1 and count[obs.CKPT_WRITE] == 1
-    # the save's write runs on the save worker, off the step loop
+    assert count[obs.CKPT_FETCH] == 1
+    # the save's host copy and write run on the save worker, off the
+    # step loop
     snap = next(s for s in tr if s.name == obs.CKPT_SNAPSHOT)
     write = next(s for s in tr if s.name == obs.CKPT_WRITE)
+    fetch = next(s for s in tr if s.name == obs.CKPT_FETCH)
     assert write.line != snap.line and write.start >= snap.end
+    assert fetch.inside(write)
 
 
 def test_counters_of_a_local_log_by_hand(time_limit):
