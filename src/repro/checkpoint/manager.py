@@ -129,13 +129,22 @@ class CheckpointManager:
         return rid
 
     def save_async(self, step: int, state,
-                   extra: Optional[Dict[str, Any]] = None) -> Future:
+                   extra: Optional[Dict[str, Any]] = None,
+                   fetch: bool = False) -> Future:
         """Overlap checkpointing with training compute.  The dedicated
         save worker serializes saves, so manifests commit in step order
         (the log's in-order-commit invariant extended to checkpoints);
-        shard writes within each save still fan out over _pool."""
+        shard writes within each save still fan out over _pool.
+
+        ``fetch=True`` waits out the state's copies to the host here,
+        on the caller, for a caller that donates the state's arrays to
+        its next computation; the encode, puts and manifest still run
+        on the save worker."""
         with obs.span(obs.CKPT_SNAPSHOT):
             state = _snapshot(state)
+            if fetch:
+                with obs.span(obs.CKPT_FETCH):
+                    state = _fetch(state)
         fut = self._save_pool.submit(self.save, step, state, extra)
         self._async.append(fut)
         return fut
@@ -285,8 +294,9 @@ def _snapshot(tree):
     on, so the copies overlap each other and the caller's next steps;
     the save worker materialises them (``_fetch``).  The image is exact
     only while the caller does not donate the arrays to a later
-    computation (``Trainer`` does not); a donated leaf would make the
-    fetch fail on a deleted array, not save wrong bytes.  Any other
+    computation before they are fetched (a ``Trainer`` that donates
+    fetches first: ``save_async(fetch=True)``); a donated leaf would make
+    the fetch fail on a deleted array, not save wrong bytes.  Any other
     leaf (NumPy) may be mutated in place by the caller, so it is
     deep-copied here.
     """

@@ -14,14 +14,18 @@ from ..models.config import (InputShape, ModelConfig, SHAPES,
                              applicable_shapes)
 from . import (command_r_35b, deepseek_v3_671b, gemma2_9b, hubert_xlarge,
                jamba_1_5_large_398b, llava_next_34b, mamba2_130m,
-               moonshot_v1_16b_a3b, qwen2_7b, starcoder2_3b)
+               moonshot_v1_16b_a3b, nemotron3_nano_30b_a3b, qwen2_7b,
+               starcoder2_3b)
 
 _MODULES = [hubert_xlarge, moonshot_v1_16b_a3b, deepseek_v3_671b,
             mamba2_130m, jamba_1_5_large_398b, starcoder2_3b, gemma2_9b,
-            command_r_35b, qwen2_7b, llava_next_34b]
+            command_r_35b, qwen2_7b, llava_next_34b, nemotron3_nano_30b_a3b]
 
 ARCHS: Dict[str, ModelConfig] = {m.CONFIG.name: m.CONFIG for m in _MODULES}
 ARCH_NAMES = list(ARCHS)
+# presets with a reduced variant of their own (under its own name)
+_REDUCED: Dict[str, ModelConfig] = {m.CONFIG.name: m.REDUCED
+                                    for m in _MODULES if hasattr(m, "REDUCED")}
 
 
 def get_config(name: str) -> ModelConfig:
@@ -33,6 +37,8 @@ def get_config(name: str) -> ModelConfig:
 def reduced_config(name: str) -> ModelConfig:
     """Same family/features, smoke-test scale (CPU-runnable)."""
     cfg = get_config(name)
+    if name in _REDUCED:
+        return _REDUCED[name]
     kw: Dict[str, Any] = dict(
         d_model=128,
         n_heads=4,

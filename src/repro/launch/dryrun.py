@@ -244,7 +244,7 @@ def measure_block(cfg: ModelConfig, shape_name: str, mesh,
         def fn(bp, h):
             def loss(bp, h):
                 out, _, aux = M.apply_block(bp, h, cfg, None, None)
-                return jnp.sum(out.astype(jnp.float32) ** 2) + aux
+                return jnp.sum(out.astype(jnp.float32) ** 2) + aux["aux"]
             g = jax.grad(loss, argnums=(0, 1))(bp, h)
             return g
         args = (bspecs, h_spec)

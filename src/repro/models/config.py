@@ -17,7 +17,8 @@ from typing import List, Optional, Tuple
 
 @dataclass(frozen=True)
 class LayerKind:
-    mixer: str          # "attn" | "ssm"
+    mixer: str          # "attn" | "ssm" | "moe" (the last: an expert
+                        # layer of a ``layer_pattern``)
     moe: bool = False
     local: bool = False  # sliding-window attention layer (gemma2)
 
@@ -40,7 +41,7 @@ class ModelConfig:
     qkv_bias: bool = False
     mlp_bias: bool = False
     gated_mlp: bool = True       # False => 2-matrix FFN (starcoder2/hubert)
-    mlp_act: str = "silu"        # silu | gelu
+    mlp_act: str = "silu"        # silu | gelu | relu2 (relu(x)^2)
     attn_logit_softcap: Optional[float] = None
     final_logit_softcap: Optional[float] = None
     sliding_window: Optional[int] = None   # window for "local" layers
@@ -68,6 +69,15 @@ class ModelConfig:
     first_dense_layers: int = 0  # leading dense layers (deepseek: 3)
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.001
+    moe_shared_d_ff: int = 0     # 0 => moe_d_ff * n_shared_experts
+    router_score: str = "softmax"  # softmax | sigmoid (deepseek-v3 style)
+    routed_scaling: float = 1.0  # routed experts' gates scaled by this
+    # expert parallelism: this chip holds experts [expert_offset,
+    # expert_offset + experts_held) of the n_experts the router scores;
+    # 0 => all of them, through the capacity-bound moe_ffn.  Held experts
+    # run the dropless moe_held_ffn.
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # SSM (mamba2 / jamba)
     attn_layer_period: int = 0   # hybrid: 1 attn per this many layers
@@ -77,6 +87,15 @@ class ModelConfig:
     ssm_conv_width: int = 4
     ssm_chunk: int = 256
     ssm_n_groups: int = 1
+    ssm_n_heads: int = 0         # 0 => ssm_expand * d_model // ssm_head_dim
+    ssm_gate_first: bool = False  # nemotron-h: y*silu(z), then RMSNorm per
+                                  # B/C group (else rmsnorm(y)*silu(z))
+
+    # nemotron-h: one mixer per residual layer, by letter: M mamba2,
+    # E experts, * attention ("" => block_pattern's rules)
+    layer_pattern: str = ""
+    use_rope: bool = True
+    attn_chunk_q: int = 512      # query block of the blockwise attention
 
     # multi-token prediction (deepseek-v3)
     mtp_depth: int = 0
@@ -90,7 +109,8 @@ class ModelConfig:
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
     norm_eps: float = 1e-6
-    remat: str = "block"         # none | block (checkpoint each block)
+    remat: str = "block"         # none | block | layer (checkpoint each
+                                 # block, or each layer of a block)
     scan_unroll: bool = False    # unroll the block scan (accurate HLO
                                  # FLOP counts for roofline; bigger HLO)
 
@@ -102,6 +122,8 @@ class ModelConfig:
     @property
     def block_period(self) -> int:
         """Layers per scanned block (the repeating pattern length)."""
+        if self.layer_pattern:
+            return len(self.layer_pattern)
         p = 1
         if self.attn_layer_period:
             p = self.attn_layer_period
@@ -122,6 +144,8 @@ class ModelConfig:
 
     def block_pattern(self) -> List[LayerKind]:
         """Layer kinds inside one block (identical across blocks)."""
+        if self.layer_pattern:
+            return [_PATTERN_KINDS[c] for c in self.layer_pattern]
         kinds = []
         for i in range(self.block_period):
             if self.attn_layer_period:
@@ -155,6 +179,11 @@ class ModelConfig:
     def model_flops_per_token(self) -> int:
         """6·N_active (the §Roofline MODEL_FLOPS convention)."""
         return 6 * self.active_param_count()
+
+
+_PATTERN_KINDS = {"M": LayerKind(mixer="ssm"),
+                  "E": LayerKind(mixer="moe", moe=True),
+                  "*": LayerKind(mixer="attn")}
 
 
 def _lcm(a: int, b: int) -> int:

@@ -17,6 +17,7 @@ Attention comes in three execution strategies, chosen by shape:
 from __future__ import annotations
 
 import math
+import os
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -95,6 +96,18 @@ def _direct_attention(q, k, v, *, scale, causal, window, cap,
     return jnp.einsum("bkgqt,btkd->bqkgd", p, v)
 
 
+def _softmax_rows(s):
+    """Softmax over the last axis, stabilised by the row's maximum held
+    behind an optimization barrier: fused with the subtraction, XLA's
+    TPU compiler turns the maximum into a reduce-window as wide as the
+    row at every position, quadratic in the key length (1.5 s a pass of
+    a 32-head layer over 2 x 8192 positions on a TPU v5e)."""
+    m = lax.optimization_barrier(
+        lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
+    e = jnp.exp(s - m)
+    return e / jnp.sum(e, axis=-1, keepdims=True)
+
+
 def _blockwise_attention(q, k, v, *, scale, causal, window, cap,
                          q_offset, chunk_q, unroll):
     """q-chunked attention against full K/V (memory O(chunk_q × Sk)).
@@ -117,7 +130,7 @@ def _blockwise_attention(q, k, v, *, scale, causal, window, cap,
         qpos = q_offset + qidx * chunk_q + jnp.arange(chunk_q)
         kpos = jnp.arange(k.shape[1])
         s = s + _mask_bias(qpos, kpos, causal, window, None)
-        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        p = _softmax_rows(s).astype(v.dtype)
         out = jnp.einsum("bkgqt,btkd->bqkgd", p, v)
         return None, out
 
@@ -219,15 +232,17 @@ def gqa_attention(x, p, cfg: ModelConfig, *, local: bool,
     v = jnp.einsum("bsd,dkh->bskh", x, p["wv"])
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    pos0 = 0 if index is None else index
-    positions = (pos0 + jnp.arange(S))[None, :]
-    cos, sin = rope_tables(positions, hd, cfg.rope_theta)
-    q = apply_rope(q.reshape(B, S, -1, hd), cos, sin).reshape(q.shape)
-    k = apply_rope(k, cos, sin)
+    if cfg.use_rope:
+        pos0 = 0 if index is None else index
+        positions = (pos0 + jnp.arange(S))[None, :]
+        cos, sin = rope_tables(positions, hd, cfg.rope_theta)
+        q = apply_rope(q.reshape(B, S, -1, hd), cos, sin).reshape(q.shape)
+        k = apply_rope(k, cos, sin)
     window = cfg.sliding_window if local else None
     if cache is None:
         o = attention(q, k, v, causal=cfg.causal, window=window,
-                      cap=cfg.attn_logit_softcap, unroll=cfg.scan_unroll)
+                      cap=cfg.attn_logit_softcap, chunk_q=cfg.attn_chunk_q,
+                      unroll=cfg.scan_unroll)
         new_cache = None
     else:
         ck = lax.dynamic_update_slice_in_dim(
@@ -240,7 +255,7 @@ def gqa_attention(x, p, cfg: ModelConfig, *, local: bool,
             # the still-empty tail of the cache buffer.
             o = attention(q, k, v, causal=cfg.causal, window=window,
                           cap=cfg.attn_logit_softcap,
-                          unroll=cfg.scan_unroll)
+                          chunk_q=cfg.attn_chunk_q, unroll=cfg.scan_unroll)
         else:
             o = attention(q, ck, cv, causal=False, window=window,
                           cap=cfg.attn_logit_softcap, q_offset=index,
@@ -371,19 +386,26 @@ def mlp_params_shapes(cfg: ModelConfig, d_ff: int) -> Dict[str, Tuple]:
 
 
 def _act(x, kind: str):
+    x = x.astype(jnp.float32)
+    if kind == "relu2":
+        return jnp.square(jax.nn.relu(x))
     f = jax.nn.gelu if kind == "gelu" else jax.nn.silu
-    return f(x.astype(jnp.float32))
+    return f(x)
+
+
+def _glu(h, cfg: ModelConfig, dtype):
+    """h [..., n_in, F] -> activation [..., F]: act(gate) * up when the
+    MLP is gated, else act(h)."""
+    if cfg.gated_mlp:
+        return _act(h[..., 0, :], cfg.mlp_act).astype(dtype) * h[..., 1, :]
+    return _act(h[..., 0, :], cfg.mlp_act).astype(dtype)
 
 
 def mlp(x, p, cfg: ModelConfig):
     h = jnp.einsum("bsd,dcf->bscf", x, p["wi"])
     if cfg.mlp_bias:
         h = h + p["bi"]
-    if cfg.gated_mlp:
-        gate, up = h[..., 0, :], h[..., 1, :]
-        act = _act(gate, cfg.mlp_act).astype(x.dtype) * up
-    else:
-        act = _act(h[..., 0, :], cfg.mlp_act).astype(x.dtype)
+    act = _glu(h, cfg, x.dtype)
     y = jnp.einsum("bsf,fd->bsd", act, p["wo"])
     if cfg.mlp_bias:
         y = y + p["bo"]
@@ -391,15 +413,66 @@ def mlp(x, p, cfg: ModelConfig):
 
 
 def moe_params_shapes(cfg: ModelConfig) -> Dict[str, Tuple]:
+    """The router scores all ``n_experts``; the expert weights are those
+    of the experts this chip holds (all of them unless ``experts_held``)."""
     D, E, F = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    El = cfg.experts_held or E
+    n_in = 2 if cfg.gated_mlp else 1
     shapes = {
         "router": (D, E),
-        "experts": {"wi": (E, D, 2, F), "wo": (E, F, D)},
+        "experts": {"wi": (El, D, n_in, F), "wo": (El, F, D)},
     }
     if cfg.n_shared_experts:
         shapes["shared"] = mlp_params_shapes(
-            cfg, cfg.moe_d_ff * cfg.n_shared_experts)
+            cfg, cfg.moe_shared_d_ff or cfg.moe_d_ff * cfg.n_shared_experts)
     return shapes
+
+
+def _route(xt, router, cfg: ModelConfig):
+    """Scores [T,E], gates [T,K] (f32) and expert ids [T,K] of tokens
+    ``xt [T,D]``.  Softmax scores are taken from a product in the compute
+    dtype; sigmoid scores (DeepSeek-V3-style router) in float32, as the
+    published router computes them (at the highest precision: at the
+    default one the TPU takes a float32 product in one bfloat16 pass).
+    The top-k gates are renormalised to sum 1 and then scaled by
+    ``routed_scaling``.  The gates are taken from the scores through a
+    one-hot product at the highest precision rather than top_k's values:
+    the same numbers, and a backward pass without top_k's scatter, which
+    the TPU runs slowly."""
+    hi = lax.Precision.HIGHEST
+    if cfg.router_score == "sigmoid":
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            router.astype(jnp.float32), precision=hi)
+        scores = jax.nn.sigmoid(logits)
+    else:
+        logits = jnp.einsum("td,de->te", xt, router,
+                            preferred_element_type=jnp.float32)
+        scores = jax.nn.softmax(logits, axis=-1)
+    _, gidx = lax.top_k(lax.stop_gradient(scores), cfg.experts_per_token)
+    gate = jnp.einsum("tke,te->tk", jax.nn.one_hot(
+        gidx, cfg.n_experts, dtype=scores.dtype), scores, precision=hi)
+    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    if cfg.routed_scaling != 1.0:
+        gate = gate * cfg.routed_scaling
+    return scores, gate, gidx
+
+
+def _held_ids(gidx, cfg: ModelConfig):
+    """Expert ids [T,K] -> flat local ids of the held experts, with
+    ``El`` (one past the last held expert) for every other expert."""
+    El = cfg.experts_held or cfg.n_experts
+    local = gidx.reshape(-1) - cfg.expert_offset
+    return jnp.where((local >= 0) & (local < El), local, El), El
+
+
+def _switch_aux(scores, gidx, cfg: ModelConfig):
+    """Switch-style load-balance auxiliary over all scored experts."""
+    if not cfg.router_aux_weight:
+        return jnp.zeros((), jnp.float32)
+    E, T = cfg.n_experts, scores.shape[0]
+    me = scores.mean(axis=0)                                   # [E]
+    ce = jnp.zeros(E).at[gidx.reshape(-1)].add(1.0) / (T * gidx.shape[1])
+    return cfg.router_aux_weight * E * jnp.sum(me * ce)
 
 
 # -- expert parallelism (shard_map all-to-all dispatch) ----------------- #
@@ -518,34 +591,32 @@ def moe_ffn(x, p, cfg: ModelConfig):
     compiled FLOPs ≈ active FLOPs × cf, not × n_experts (the dense
     one-hot dispatch pathology).  With EP enabled (set_moe_ep) and a
     compatible shape, dispatch runs as shard_map all-to-alls instead.
-    Returns (y, aux_loss).
+    With ``experts_held`` only the held experts compute, each up to the
+    capacity; pairs routed elsewhere are left out.
+    Returns (y, aux_loss, rows computed per held expert | None for EP).
     """
     if _moe_ep_applicable(x, cfg):
-        return _moe_ffn_ep(x, p, cfg)
+        y, aux = _moe_ffn_ep(x, p, cfg)
+        return y, aux, None
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.experts_per_token
     T = B * S
     xt = x.reshape(T, D)
-    logits = jnp.einsum("td,de->te", xt, p["router"],
-                        preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, gidx = lax.top_k(probs, K)                  # [T,K]
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    probs, gate, gidx = _route(xt, p["router"], cfg)   # [T,K]
 
-    flat_e = gidx.reshape(-1)                          # [T*K]
+    flat_e, El = _held_ids(gidx, cfg)                  # [T*K]
     order = jnp.argsort(flat_e)
     sorted_e = flat_e[order]
     tok = order // K
-    starts = jnp.searchsorted(sorted_e, jnp.arange(E))
-    pos = jnp.arange(T * K) - starts[sorted_e]
+    starts = jnp.searchsorted(sorted_e, jnp.arange(El))
+    pos = jnp.arange(T * K) - starts[jnp.minimum(sorted_e, El - 1)]
     C = max(1, int(math.ceil(T * K / E * cfg.capacity_factor)))
-    pos = jnp.where(pos < C, pos, C)                   # C => dropped
+    pos = jnp.where((pos < C) & (sorted_e < El), pos, C)   # C => dropped
 
-    buf = jnp.zeros((E, C, D), x.dtype)
+    buf = jnp.zeros((El, C, D), x.dtype)
     buf = buf.at[sorted_e, pos].set(xt[tok], mode="drop")
     h = jnp.einsum("ecd,edgf->ecgf", buf, p["experts"]["wi"])
-    act = jax.nn.silu(h[..., 0, :].astype(jnp.float32)).astype(x.dtype) \
-        * h[..., 1, :]
+    act = _glu(h, cfg, x.dtype)
     out_buf = jnp.einsum("ecf,efd->ecd", act, p["experts"]["wo"])
 
     contrib = out_buf.at[sorted_e, pos].get(mode="fill", fill_value=0.0)
@@ -555,12 +626,125 @@ def moe_ffn(x, p, cfg: ModelConfig):
 
     if cfg.n_shared_experts:
         y = y + mlp(x, p["shared"], cfg)
+    load = jnp.minimum(
+        jnp.zeros(El, jnp.int32).at[flat_e].add(1, mode="drop"), C)
+    return y, _switch_aux(probs, gidx, cfg), load
 
-    # switch-style load-balance auxiliary
-    me = probs.mean(axis=0)                                    # [E]
-    ce = jnp.zeros(E).at[flat_e].add(1.0) / (T * K)
-    aux = cfg.router_aux_weight * E * jnp.sum(me * ce)
-    return y, aux
+
+# -- dropless held experts (grouped matrix products) -------------------- #
+def _gmm_tile(d: int) -> int:
+    """The largest multiple of 128 up to 1024 that pads ``d`` by at most
+    4%; ``d`` itself below 128."""
+    if d < 128:
+        return d
+    for t in range(1024, 127, -128):
+        if -(-d // t) * t <= 1.04 * d:
+            return t
+    return 128
+
+
+def _gmm_tiles(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    return (512 if m % 512 == 0 else 128), _gmm_tile(k), _gmm_tile(n)
+
+
+def _gmm(lhs, rhs, sizes):
+    """Grouped product: rows ``sum(sizes[:g])`` to ``sum(sizes[:g+1])`` of
+    ``lhs [M,K]`` times ``rhs[g] [K,N]``; rows past ``sum(sizes)`` are
+    undefined.  Megablox's Pallas kernel on TPU (or with
+    REPRO_USE_PALLAS=1, interpreted), ``lax.ragged_dot`` elsewhere."""
+    use = os.environ.get("REPRO_USE_PALLAS") == "1" or \
+        jax.default_backend() == "tpu"
+    if use:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        return gmm(lhs, rhs, sizes, lhs.dtype, _gmm_tiles, None, None,
+                   False, jax.default_backend() != "tpu")
+    return lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=lhs.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _dispatch(xt, order, inv, M: int, K: int):
+    """Rows ``xt[order[:M] // K]``: the tokens of the first M (token,
+    slot) pairs in ``order``, a permutation of the T*K pairs whose
+    inverse is ``inv``.  Its backward pass is a gather too: a token's
+    gradient is the sum over its K pairs' rows, found through ``inv``
+    (autodiff would scatter-add, which the TPU runs slowly)."""
+    return xt[order[:M] // K]
+
+
+def _dispatch_fwd(xt, order, inv, M, K):
+    return _dispatch(xt, order, inv, M, K), inv
+
+
+def _dispatch_bwd(M, K, inv, g):
+    gpad = jnp.pad(g, ((0, inv.shape[0] - M), (0, 0)))
+    gx = gpad[inv].reshape(-1, K, g.shape[-1]).astype(jnp.float32).sum(1)
+    return gx.astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _undispatch(o, order, inv, M: int):
+    """Rows of ``o [M, D]`` back in (token, slot) order, [T*K, D]: pair p
+    takes row inv[p], or zeros where inv[p] >= M.  Its backward pass is
+    the gather by ``order``."""
+    return jnp.pad(o, ((0, inv.shape[0] - M), (0, 0)))[inv]
+
+
+def _undispatch_fwd(o, order, inv, M):
+    return _undispatch(o, order, inv, M), order
+
+
+def _undispatch_bwd(M, order, g):
+    return g[order[:M]], None, None
+
+
+_undispatch.defvjp(_undispatch_fwd, _undispatch_bwd)
+
+
+def moe_held_ffn(x, p, cfg: ModelConfig):
+    """Dropless MoE over the experts this chip holds.
+
+    The router scores all ``n_experts`` and picks each token's top k;
+    the (token, expert) pairs whose expert is held are sorted by expert
+    and each held expert's rows go through two grouped matrix products
+    (``_gmm``), the group sizes staying on the device.  The output is
+    the held experts' gated part of the layer plus the shared expert:
+    what the other chips' experts add is not computed here.  The row
+    buffer is sized for the worst case, every token routed to
+    min(k, held) held experts, so no row is ever dropped; its rows past
+    the routed ones are held at zero (the grouped products leave them
+    undefined), in both passes.  Rows move by gathers only.
+    Returns (y, aux, rows per held expert [held])."""
+    B, S, D = x.shape
+    K = cfg.experts_per_token
+    T = B * S
+    xt = x.reshape(T, D)
+    scores, gate, gidx = _route(xt, p["router"], cfg)
+    local, El = _held_ids(gidx, cfg)                   # [T*K]
+    sizes = jnp.sum(local[:, None] == jnp.arange(El), axis=0,
+                    dtype=jnp.int32)
+    M = -(-T * min(K, El) // 128) * 128
+    Tp = max(T, -(-M // K))            # tokens, padded where T*K < M
+    xp = jnp.pad(xt, ((0, Tp - T), (0, 0)))
+    local = jnp.pad(local, (0, (Tp - T) * K), constant_values=El)
+    order = jnp.argsort(local)                         # held pairs first
+    inv = jnp.argsort(order)
+    valid = (jnp.arange(M) < sizes.sum())[:, None]
+    xs = jnp.where(valid, _dispatch(xp, order, inv, M, K), 0)
+    wi, wo = p["experts"]["wi"], p["experts"]["wo"]
+    F = wo.shape[1]
+    h = jnp.where(valid, _gmm(xs, wi.reshape(El, D, -1), sizes), 0)
+    act = _glu(h.reshape(M, -1, F), cfg, x.dtype)
+    o = jnp.where(valid, _gmm(act, wo, sizes), 0)      # [M, D]
+    pairs = _undispatch(o, order, inv, M)[:T * K].reshape(T, K, D)
+    y = jnp.einsum("tkd,tk->td", pairs, gate.astype(o.dtype),
+                   preferred_element_type=jnp.float32)
+    y = y.astype(x.dtype).reshape(B, S, D)
+    if cfg.n_shared_experts:
+        y = y + mlp(x, p["shared"], cfg)
+    return y, _switch_aux(scores, gidx, cfg), sizes
 
 
 # ---------------------------------------------------------------------- #
@@ -568,6 +752,10 @@ def moe_ffn(x, p, cfg: ModelConfig):
 # ---------------------------------------------------------------------- #
 
 def ssm_dims(cfg: ModelConfig) -> Tuple[int, int, int, int]:
+    if cfg.ssm_n_heads:
+        n_heads = cfg.ssm_n_heads
+        d_inner = n_heads * cfg.ssm_head_dim
+        return d_inner, n_heads, cfg.ssm_head_dim, cfg.ssm_state_dim
     d_inner = cfg.ssm_expand * cfg.d_model
     n_heads = d_inner // cfg.ssm_head_dim
     return d_inner, n_heads, cfg.ssm_head_dim, cfg.ssm_state_dim
@@ -636,8 +824,15 @@ def ssm_mixer(x, p, cfg: ModelConfig,
         new_cache = {"conv": new_conv, "state": new_state}
     y = y + xh * p["D_skip"].astype(y.dtype)[None, None, :, None]
     y = y.reshape(B, S, di)
-    y = rms_norm(y, p["out_norm"], cfg.norm_eps)
-    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+    if cfg.ssm_gate_first:
+        # gate, then RMSNorm over each B/C group's share of the width
+        g = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32)))
+        g = rms_norm(g.reshape(B, S, G, di // G),
+                     p["out_norm"].reshape(G, di // G), cfg.norm_eps)
+        y = g.reshape(B, S, di).astype(y.dtype)
+    else:
+        y = rms_norm(y, p["out_norm"], cfg.norm_eps)
+        y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
     return out, new_cache
 

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs
 from . import layers as L
 from .config import InputShape, LayerKind, ModelConfig
 
@@ -82,13 +83,16 @@ def _spec_tree(shapes, cfg: ModelConfig, fp32_keys=("norm", "a_log",
 def _layer_shapes(cfg: ModelConfig, kind: LayerKind) -> Dict[str, Any]:
     D = cfg.d_model
     s: Dict[str, Any] = {"ln1": {"w": (D,)}}
+    if kind.mixer == "moe":                    # single-mixer layer
+        s["ffn"] = L.moe_params_shapes(cfg)
+        return s
     if kind.mixer == "attn":
         s["attn"] = L.mla_params_shapes(cfg) if cfg.use_mla \
             else L.gqa_params_shapes(cfg)
     else:
         s["ssm"] = L.ssm_params_shapes(cfg)
-    has_ffn = kind.moe or cfg.d_ff > 0
-    if not has_ffn:                      # mamba2: layer = mixer only
+    has_ffn = (kind.moe or cfg.d_ff > 0) and not cfg.layer_pattern
+    if not has_ffn:                      # mamba2, nemotron-h: mixer only
         return s
     if not cfg.parallel_block:
         s["ln2"] = {"w": (D,)}
@@ -179,11 +183,43 @@ def _cast_compute(p, cfg: ModelConfig):
     return jax.tree_util.tree_map(conv, p)
 
 
+def _zero_aux(cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """What the layers add up besides the residual stream: the router's
+    auxiliary loss and, where experts are held, the rows the held
+    experts computed and the largest held expert's rows."""
+    aux = {"aux": jnp.zeros((), jnp.float32)}
+    if cfg.experts_held:
+        aux[obs.MOE_ROWS] = jnp.zeros((), jnp.int32)
+        aux[obs.MOE_MAX_ROWS] = jnp.zeros((), jnp.int32)
+    return aux
+
+
+def _add_aux(a, b):
+    return jax.tree_util.tree_map(jnp.add, a, b)
+
+
+def _moe_aux(cfg: ModelConfig, aux, load):
+    out = _zero_aux(cfg)
+    out["aux"] = aux
+    if cfg.experts_held:
+        out[obs.MOE_ROWS] = load.sum()
+        out[obs.MOE_MAX_ROWS] = load.max()
+    return out
+
+
 def _apply_layer(h, p, cfg: ModelConfig, kind: LayerKind, cache, index):
     """One residual layer.  Returns (h, new_cache, aux)."""
+    raw = p
     p = _cast_compute(p, cfg)
-    aux = jnp.zeros((), jnp.float32)
+    aux = _zero_aux(cfg)
     u = L.rms_norm(h, p["ln1"]["w"], cfg.norm_eps)
+    if kind.mixer == "moe":                    # single-mixer expert layer
+        ffn = p["ffn"]
+        if cfg.router_score == "sigmoid":      # router kept in float32
+            ffn = dict(ffn, router=raw["ffn"]["router"])
+        moe = L.moe_held_ffn if cfg.experts_held else L.moe_ffn
+        ff, a, load = moe(u, ffn, cfg)
+        return h + ff, cache, _moe_aux(cfg, a, load)
     if kind.mixer == "attn":
         mix, new_cache = (L.mla_attention if cfg.use_mla else
                           partial(L.gqa_attention, local=kind.local))(
@@ -200,7 +236,8 @@ def _apply_layer(h, p, cfg: ModelConfig, kind: LayerKind, cache, index):
     h = h + mix
     u2 = L.rms_norm(h, p["ln2"]["w"], cfg.norm_eps)
     if kind.moe:
-        ff, aux = L.moe_ffn(u2, p["ffn"], cfg)
+        ff, a, load = L.moe_ffn(u2, p["ffn"], cfg)
+        aux = _moe_aux(cfg, a, load)
     else:
         ff = L.mlp(u2, p["ffn"], cfg)
     if cfg.use_post_norm:
@@ -242,25 +279,29 @@ def apply_block(bp, h, cfg: ModelConfig, bc=None, index=None):
     Returns (h, new_block_cache, aux)."""
     pattern = cfg.block_pattern()
     ncs = {}
-    aux_acc = jnp.zeros((), jnp.float32)
+    aux_acc = _zero_aux(cfg)
     for i, kind in enumerate(pattern):
         c = None if bc is None else bc[f"l{i}"]
-        h, nc, aux = _apply_layer(h, bp[f"l{i}"], cfg, kind, c, index)
-        aux_acc = aux_acc + aux
+        layer = partial(_apply_layer, cfg=cfg, kind=kind, index=index)
+        if cfg.remat == "layer" and c is None:
+            layer = jax.checkpoint(
+                layer, policy=jax.checkpoint_policies.nothing_saveable)
+        h, nc, aux = layer(h, bp[f"l{i}"], cache=c)
+        aux_acc = _add_aux(aux_acc, aux)
         ncs[f"l{i}"] = nc if nc is not None else {}
     return h, ncs, aux_acc
 
 
 def _run_stack(params: Params, cfg: ModelConfig, h, cache, index):
     """Dense prologue + scanned blocks.  Returns (h, new_cache, aux)."""
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _zero_aux(cfg)
     new_cache: Dict[str, Any] = {}
     dense_kind = LayerKind(mixer="attn")
     for i in range(cfg.first_dense_layers):
         c = None if cache is None else cache[f"dense{i}"]
         h, nc, aux = _apply_layer(h, params[f"dense{i}"], cfg, dense_kind,
                                   c, index)
-        aux_total += aux
+        aux_total = _add_aux(aux_total, aux)
         if nc is not None:
             new_cache[f"dense{i}"] = nc
 
@@ -268,7 +309,7 @@ def _run_stack(params: Params, cfg: ModelConfig, h, cache, index):
         hh, aux_acc = carry
         bp, bc = xs
         hh, ncs, aux = apply_block(bp, _constrain(hh), cfg, bc, index)
-        return (_constrain(hh), aux_acc + aux), ncs
+        return (_constrain(hh), _add_aux(aux_acc, aux)), ncs
 
     body = block_body
     if cfg.remat == "block":
@@ -336,7 +377,8 @@ def forward_train(params: Params, cfg: ModelConfig, batch: Dict[str, Any]
     h, _, aux = _run_stack(params, cfg, h, cache=None, index=None)
     logits = _logits(params, cfg, h)
     loss = cross_entropy(logits, batch["labels"])
-    metrics = {"ce": loss, "aux": aux}
+    metrics = {"ce": loss, **aux}
+    aux = aux["aux"]
     if cfg.mtp_depth and "tokens" in batch:
         loss_mtp = _mtp_loss(params, cfg, h, batch)
         metrics["mtp"] = loss_mtp

@@ -7,7 +7,8 @@ on the host planes of the session's ``.xplane.pb``, on the same clock
 as the device's ops, one line per thread.  The session is the only
 switch.
 
-Every name the program emits is one of the constants below.  The only
+Every name the program emits is one of the constants below: the spans
+(``NAMES``) and the counters (``COUNTERS``).  The only
 metadata is ``round=<end LSN>`` on a durability round's issue, lane
 write and retire spans, so that one round can be followed across the
 threads that handle it.
@@ -68,6 +69,25 @@ CKPT_FETCH = "arcadia.ckpt.fetch"         # waiting out the state's copies
 NAMES = frozenset(v for k, v in list(globals().items())
                   if k.isupper() and isinstance(v, str)
                   and v.startswith("arcadia."))
+
+# counters: running totals a reader takes the difference of over a window
+COLLECTED = "collected"                   # IngestEngine.stats(): records
+QUEUE_WAIT_S = "queue_wait_s"             # taken by the collector; their
+                                          # summed wait in the queue
+ROUNDS_RETIRED = "rounds_retired"         # Log.stats(): durability rounds
+ROUND_WALL_S = "round_wall_s"             # retired; their issue-to-retire
+                                          # seconds
+CKPTS_SAVED = "ckpts_saved"               # TrainerReport: checkpoints
+CKPTS_SKIPPED = "ckpts_skipped"           # dispatched; skipped because
+                                          # the previous one still wrote
+MOE_ROWS = "moe_rows"                     # TrainerReport and the step's
+MOE_MAX_ROWS = "moe_max_rows"             # metrics: rows the held experts
+                                          # computed, and the largest held
+                                          # expert's rows, summed over the
+                                          # steps and the expert layers
+
+COUNTERS = frozenset((COLLECTED, QUEUE_WAIT_S, ROUNDS_RETIRED, ROUND_WALL_S,
+                      CKPTS_SAVED, CKPTS_SKIPPED, MOE_ROWS, MOE_MAX_ROWS))
 
 
 def span(name: str, **meta) -> TraceAnnotation:

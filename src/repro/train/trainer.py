@@ -34,7 +34,11 @@ from ..checkpoint import CheckpointManager
 from ..data import SyntheticDataset
 from ..models.config import ModelConfig
 from ..optim import OptConfig
-from .step import init_train_state, make_train_step
+from .step import init_train_state, make_train_step, train_state_specs
+
+
+# the step's metrics read back each step, in one transfer
+_READ = ("loss", obs.MOE_ROWS, obs.MOE_MAX_ROWS)
 
 
 @dataclass
@@ -53,6 +57,9 @@ class TrainerReport:
     losses: List[float] = field(default_factory=list)
     ckpts_saved: int = 0
     ckpts_skipped: int = 0       # straggler mitigation skips
+    moe_rows: int = 0            # rows the held experts computed
+    moe_max_rows: int = 0        # the largest held expert's rows, per
+                                 # step and expert layer, summed
     restarts: int = 0
     restored_step: Optional[int] = None
 
@@ -68,10 +75,14 @@ class Trainer:
         self.mgr = mgr
         self.tcfg = tcfg
         self.report = TrainerReport()
-        # no donate_argnums: a step leaves its input state intact, so an
+        # Without donation a step leaves its input state intact, so an
         # async save may hold the state's arrays by reference while later
-        # steps run (checkpoint.manager._snapshot)
-        self._step_fn = jax.jit(make_train_step(cfg, opt_cfg))
+        # steps run (checkpoint.manager._snapshot).  A state too large
+        # to be held twice is donated, and a save then fetches it to the
+        # host before the next step.
+        self.donates = _donates(cfg, opt_cfg)
+        self._step_fn = jax.jit(make_train_step(cfg, opt_cfg),
+                                donate_argnums=(0,) if self.donates else ())
         self._pending_save = None
         self.state = None
 
@@ -111,7 +122,12 @@ class Trainer:
             with obs.span(obs.TRAIN_STEP):
                 self.state, metrics = self._step_fn(self.state, batch)
             with obs.span(obs.TRAIN_LOSS):
-                loss = float(metrics["loss"])
+                got = jax.device_get({k: metrics[k] for k in _READ
+                                      if k in metrics})
+            loss = float(got["loss"])
+            if obs.MOE_ROWS in got:
+                self.report.moe_rows += int(got[obs.MOE_ROWS])
+                self.report.moe_max_rows += int(got[obs.MOE_MAX_ROWS])
             self.report.losses.append(loss)
             self.report.steps_run += 1
             if s % self.tcfg.journal_every == 0:
@@ -131,8 +147,8 @@ class Trainer:
                     not self._pending_save.done():
                 self.report.ckpts_skipped += 1   # straggler: skip over
                 return
-            self._pending_save = self.mgr.save_async(step, self.state,
-                                                     extra)
+            self._pending_save = self.mgr.save_async(
+                step, self.state, extra, fetch=self.donates)
         else:
             self.mgr.save(step, self.state, extra, sync=True)
         self.report.ckpts_saved += 1
@@ -142,3 +158,14 @@ class Trainer:
         last = self.mgr.log.next_lsn - 1
         if last >= 1 and self.mgr.log.durable_lsn < last:
             self.mgr.log.force(last, freq=1)
+
+
+def _donates(cfg: ModelConfig, opt_cfg: OptConfig) -> bool:
+    """Whether two copies of the train state would take more than half
+    of the device's memory (which a CPU does not report)."""
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        return False
+    nbytes = sum(x.size * x.dtype.itemsize for x in
+                 jax.tree_util.tree_leaves(train_state_specs(cfg, opt_cfg)))
+    return 2 * nbytes > limit // 2

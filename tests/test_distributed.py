@@ -126,10 +126,10 @@ def test_moe_expert_parallel_matches_dense_path():
                  "wo": jnp.asarray(rng.normal(
                      size=(cfg.n_experts, cfg.moe_d_ff, D)) * 0.05,
                      jnp.float32)}}
-        y_ref, _ = jax.jit(lambda x, p: L.moe_ffn(x, p, cfg))(x, p)
+        y_ref, _, _ = jax.jit(lambda x, p: L.moe_ffn(x, p, cfg))(x, p)
         L.set_moe_ep(mesh, ("data", "model"))
         with mesh:
-            y_ep, _ = jax.jit(lambda x, p: L.moe_ffn(x, p, cfg))(x, p)
+            y_ep, _, _ = jax.jit(lambda x, p: L.moe_ffn(x, p, cfg))(x, p)
             g = jax.jit(jax.grad(
                 lambda p, x: L.moe_ffn(x, p, cfg)[0].sum()))(p, x)
         L.set_moe_ep(None, None)

@@ -332,3 +332,55 @@ def test_span_costs_little_with_no_session(time_limit):
         with obs.span(obs.LOG_HASH):
             pass
     assert (time.perf_counter() - t0) / 10000 < 50e-6
+
+
+def test_counter_names_are_the_listed_ones(traced):
+    """Every counter the program keeps is in ``obs.COUNTERS``, under the
+    name a reader takes it by."""
+    state, _, _ = traced
+    s0, _ = state["ingest"]
+    l0, _ = state["log"]
+    assert obs.COUNTERS == {obs.COLLECTED, obs.QUEUE_WAIT_S,
+                            obs.ROUNDS_RETIRED, obs.ROUND_WALL_S,
+                            obs.CKPTS_SAVED, obs.CKPTS_SKIPPED,
+                            obs.MOE_ROWS, obs.MOE_MAX_ROWS}
+    assert {obs.COLLECTED, obs.QUEUE_WAIT_S} <= set(s0)
+    assert {obs.ROUNDS_RETIRED, obs.ROUND_WALL_S} <= set(l0)
+    rep = vars(state["train"])
+    assert {obs.CKPTS_SAVED, obs.CKPTS_SKIPPED, obs.MOE_ROWS,
+            obs.MOE_MAX_ROWS} <= set(rep)
+    assert rep[obs.CKPTS_SAVED] == 1 and rep[obs.CKPTS_SKIPPED] == 0
+    # a model without held experts counts no rows
+    assert rep[obs.MOE_ROWS] == rep[obs.MOE_MAX_ROWS] == 0
+
+
+def test_moe_counters_by_hand(time_limit):
+    """One step of a model with held experts: the trainer's row counters
+    are the step's own, read with its loss."""
+    from repro.checkpoint import (CheckpointManager, ObjectStore,
+                                  ReplicatedStore)
+    from repro.configs import reduced_config
+    from repro.data import DataConfig, SyntheticDataset
+    from repro.models import model as M
+    from repro.optim import OptConfig
+    from repro.train.trainer import Trainer, TrainerConfig
+    cfg = reduced_config("nemotron3-nano-30b-a3b")
+    data = SyntheticDataset(cfg, DataConfig(batch=2, seq_len=32))
+    store = ReplicatedStore([ObjectStore("s0")], write_quorum=1)
+    log = Log.create(PMEMDevice((1 << 18) + 4096),
+                     LogConfig(capacity=1 << 18))
+    mgr = CheckpointManager(store, log)
+    tr = Trainer(cfg, OptConfig(), data, mgr,
+                 TrainerConfig(total_steps=1, ckpt_every=10))
+    tr.init_or_restore()
+    batch = {k: jax.numpy.asarray(v) for k, v in data.batch_at(0).items()}
+    _, want = jax.jit(lambda p: M.forward_train(p, cfg, batch))(
+        tr.state["params"])
+    rep = tr.run()
+    mgr.close()
+    assert rep.moe_rows == int(want[obs.MOE_ROWS]) > 0
+    assert rep.moe_max_rows == int(want[obs.MOE_MAX_ROWS])
+    # 3 expert layers, 64 tokens, 3 of 16 experts a token, 4 held: at
+    # most min(3, 4) held experts a token
+    assert rep.moe_rows <= 3 * 64 * 3
+    assert rep.moe_max_rows * cfg.experts_held >= rep.moe_rows

@@ -1,7 +1,8 @@
 """The main-path kernels compile for a TPU v5e that is described, not
 attached: the checksum kernel at 1 MiB and 4 MiB inputs, the SSD kernel
-forward and ``jax.grad`` at mamba2-130m widths, and flash attention at
-8 heads of 128 over 2048 tokens.  Nothing runs;
+forward and ``jax.grad`` at mamba2-130m widths, flash attention at
+8 heads of 128 over 2048 tokens, and the XLA blockwise attention's
+softmax.  Nothing runs;
 this catches what the chip's compiler would refuse, at no chip time.
 
 The topology is described inside a module fixture (never at import):
@@ -95,3 +96,26 @@ def test_ssd_kernel_grad_compiles(one_chip):
     grad = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
     compiled = jax.jit(grad).lower(*_ssd_args(one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_blockwise_attention_softmax_is_no_row_wide_window(one_chip):
+    """Forward and ``jax.grad`` of the XLA blockwise attention at the
+    Nemotron-H stage's shape (2 x 8192 tokens, 2 KV heads of 16 queries
+    of 128, 128-query blocks; smaller shapes do not show the rewrite):
+    no reduce-window spans the key axis, which would cost the key
+    length at every position."""
+    import re
+    from repro.models.layers import attention
+    Sa = 8192
+    q = _spec((2, Sa, 2, 16, 128), jnp.bfloat16, one_chip)
+    kv = _spec((2, Sa, 2, 128), jnp.bfloat16, one_chip)
+
+    def f(q, k, v):
+        o = attention(q, k, v, causal=True, chunk_q=128)
+        return jnp.sum(jnp.square(o.astype(jnp.float32)))
+
+    txt = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    widths = [int(w) for w in
+              re.findall(r"window=\{size=(?:\d+x)*(\d+)", txt)]
+    assert max(widths, default=1) < Sa, sorted(set(widths))
