@@ -5,11 +5,15 @@ can compile for 512 host devices; the Pallas kernels in
 ``repro.kernels`` are drop-in accelerated equivalents validated against
 these (see kernels/*/ref.py).
 
-Attention comes in three execution strategies, chosen by shape:
+Attention comes in four execution strategies, chosen by shape:
   * direct      — materialized scores (short sequences, decode).
+  * flash       — long global causal self-attention on the TPU: the
+                  bundled splash kernel (Pallas, with its backward
+                  kernels), which skips key blocks above the diagonal
+                  and keeps score tiles in VMEM.
   * blockwise   — q-chunked lazy softmax against full K/V, each chunk
-                  checkpointed (long prefill; memory O(chunk·S); the
-                  Pallas flash kernel is the TPU-optimal equivalent).
+                  checkpointed (every other long global call; memory
+                  O(chunk·S)).
   * sliding     — banded gather per query chunk (local layers: O(S·w)
                   compute instead of O(S²) — gemma2's local half).
 """
@@ -18,7 +22,7 @@ from __future__ import annotations
 
 import math
 import os
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -114,8 +118,8 @@ def _blockwise_attention(q, k, v, *, scale, causal, window, cap,
 
     Each q-step is jax.checkpoint'ed so the backward pass recomputes its
     score tile instead of saving S² probabilities.  The causal half-waste
-    (masked tiles still computed) is inherent to the XLA path; the Pallas
-    flash kernel skips fully-masked tiles on TPU.
+    (masked tiles still computed) is inherent to the XLA path; on the TPU
+    ``_flash_attention`` skips fully-masked tiles.
     """
     B, Sq, K, G, D = q.shape
     Dv = v.shape[-1]
@@ -177,10 +181,55 @@ def _sliding_attention(q, k, v, *, scale, window, cap, chunk_q, unroll):
     return jnp.moveaxis(outs, 0, 1).reshape(B, Sq, K, G, Dv).astype(q.dtype)
 
 
+def _flash_block(q, k, *, causal, window, cap, q_offset, kv_len
+                 ) -> Optional[int]:
+    """Block size of the flash path for this call, or None where the call
+    keeps the XLA paths: off the TPU, and for anything but global causal
+    self-attention (no window, soft cap or cache length, queries from
+    position 0, as many keys as queries) over a length that one of the
+    blocks divides."""
+    Sq, Sk = q.shape[1], k.shape[1]
+    if (jax.default_backend() != "tpu" or not causal or window is not None
+            or cap is not None or kv_len is not None
+            or not (isinstance(q_offset, int) and q_offset == 0)
+            or Sq != Sk):
+        return None
+    return next((b for b in (1024, 512, 256) if Sq % b == 0), None)
+
+
+@lru_cache(maxsize=None)
+def _splash_kernel(heads: int, seq: int, block: int, interpret: bool):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    mask = sm.MultiHeadMask([sm.CausalMask((seq, seq))] * heads)
+    sizes = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    with jax.ensure_compile_time_eval():     # cached: concrete, not traced
+        return sk.make_splash_mha_single_device(mask, block_sizes=sizes,
+                                                interpret=interpret)
+
+
+def _flash_attention(q, k, v, *, scale, block, interpret=False):
+    """Causal self-attention through the splash kernel: q [B,S,K,G,D];
+    k,v [B,S,K,D] -> [B,S,K,G,D].  Query head k·G+g reads KV head k, the
+    kernel's own grouping; scores accumulate in f32 with f32 softmax
+    statistics.  The kernel takes no scale, so q is scaled in f32 and
+    rounded once to its dtype."""
+    B, S, K, G, D = q.shape
+    qh = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    qh = jnp.moveaxis(qh.reshape(B, S, K * G, D), 1, 2)
+    kh, vh = jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2)
+    o = jax.vmap(_splash_kernel(K * G, S, block, interpret))(qh, kh, vh)
+    return jnp.moveaxis(o, 2, 1).reshape(B, S, K, G, v.shape[-1])
+
+
 def attention(q, k, v, *, causal=True, window=None, cap=None, q_offset=0,
               kv_len=None, chunk_q=512, scale=None, unroll=False):
-    """Dispatch on shape: decode/short -> direct; long local -> sliding;
-    long global -> q-chunked lazy softmax."""
+    """Dispatch on shape: decode/short -> direct; long global causal on
+    the TPU -> flash; long local -> sliding; other long global ->
+    q-chunked lazy softmax."""
     B, Sq, K, G, D = q.shape
     Sk = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -188,6 +237,10 @@ def attention(q, k, v, *, causal=True, window=None, cap=None, q_offset=0,
         return _direct_attention(q, k, v, scale=scale, causal=causal,
                                  window=window, cap=cap, q_offset=q_offset,
                                  kv_len=kv_len)
+    block = _flash_block(q, k, causal=causal, window=window, cap=cap,
+                         q_offset=q_offset, kv_len=kv_len)
+    if block:
+        return _flash_attention(q, k, v, scale=scale, block=block)
     if window is not None and Sq % chunk_q == 0 and Sq > window:
         return _sliding_attention(q, k, v, scale=scale, window=window,
                                   cap=cap, chunk_q=chunk_q, unroll=unroll)

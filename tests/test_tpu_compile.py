@@ -1,8 +1,9 @@
 """The main-path kernels compile for a TPU v5e that is described, not
 attached: the checksum kernel at 1 MiB and 4 MiB inputs, the SSD kernel
 forward and ``jax.grad`` at mamba2-130m widths, flash attention at
-8 heads of 128 over 2048 tokens, and the XLA blockwise attention's
-softmax.  Nothing runs;
+8 heads of 128 over 2048 tokens, the XLA blockwise attention's softmax,
+and the Nemotron-H stage's attention layer through the splash kernel.
+Nothing runs;
 this catches what the chip's compiler would refuse, at no chip time.
 
 The topology is described inside a module fixture (never at import):
@@ -119,3 +120,38 @@ def test_blockwise_attention_softmax_is_no_row_wide_window(one_chip):
     widths = [int(w) for w in
               re.findall(r"window=\{size=(?:\d+x)*(\d+)", txt)]
     assert max(widths, default=1) < Sa, sorted(set(widths))
+
+
+def test_nemotron_attention_layer_compiles_through_flash(one_chip,
+                                                          monkeypatch):
+    """``gqa_attention`` at the train8k cell's widths (d_model 2688, 32
+    query heads over 2 KV heads of 128, 2 x 8192 tokens), forward and
+    ``jax.grad``: on the TPU it runs the splash kernel, with no
+    reduce-window wider than 128 and no more temporary memory than the
+    XLA blockwise path compiled at the same shape."""
+    import re
+    from repro.configs import get_config
+    from repro.models import layers
+    cfg = get_config("nemotron3-nano-30b-a3b")
+    x = _spec((2, 8192, cfg.d_model), jnp.bfloat16, one_chip)
+    p = {n: _spec(s, jnp.bfloat16, one_chip)
+         for n, s in layers.gqa_params_shapes(cfg).items()}
+
+    def loss(x, p):
+        y, _ = layers.gqa_attention(x, p, cfg, local=False)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    def compile_grad():
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, p).compile()
+
+    xla = compile_grad()                   # the CPU backend: blockwise
+    assert "tpu_custom_call" not in xla.as_text()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    flash = compile_grad()
+    txt = flash.as_text()
+    assert "tpu_custom_call" in txt
+    widths = [int(w) for w in
+              re.findall(r"window=\{size=(?:\d+x)*(\d+)", txt)]
+    assert max(widths, default=1) <= 128, sorted(set(widths))
+    assert flash.memory_analysis().temp_size_in_bytes <= \
+        xla.memory_analysis().temp_size_in_bytes
