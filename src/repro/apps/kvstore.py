@@ -291,17 +291,16 @@ class MultiTenantKV:
         return cut, tables
 
     def checkpoint_and_trim(self) -> Tuple[SnapshotCut,
-                                           Dict[bytes, Dict[bytes, bytes]],
-                                           Dict[str, float]]:
+                                           Dict[bytes, Dict[bytes, bytes]]]:
         """Snapshot-then-truncate across every tenant (DESIGN.md §13):
         materialise a coherent table state via the two-phase cut, then
         bulk-truncate each shard up to its durable watermark in that
-        cut.  Returns (cut, tables, per-shard trim vns).  The tables
-        ARE the snapshot — the caller persists them; recovery overlays
-        the surviving log suffix via ``recover_tables(logs, tables)``."""
+        cut.  Returns (cut, tables).  The tables ARE the snapshot — the
+        caller persists them; recovery overlays the surviving log suffix
+        via ``recover_tables(logs, tables)``."""
         cut, tables = self.snapshot_view()
-        trims = self.router.trim_to_cut(cut)
-        return cut, tables, trims
+        self.router.trim_to_cut(cut)
+        return cut, tables
 
     # -- tenant-scoped stats / faults ----------------------------------------- #
     def _check_owns(self, t: bytes, shard_id: str) -> None:
@@ -369,7 +368,7 @@ class BaselineKV:
 
     def put(self, key: bytes, val: bytes) -> int:
         payload = encode_put(key, val)
-        rid, _vns = self.blog.append(payload)
+        rid = self.blog.append(payload)
         with self._lock:
             self._table[key] = val
         return rid
@@ -377,7 +376,7 @@ class BaselineKV:
     def put_many(self, items: Iterable[Tuple[bytes, bytes]]) -> List[int]:
         """Baseline batch path: per-record appends under the hood."""
         items = list(items)
-        lsns, _vns = self.blog.append_batch(
+        lsns = self.blog.append_batch(
             [encode_put(k, v) for k, v in items])
         with self._lock:
             for k, v in items:

@@ -2,8 +2,7 @@
 
 Public surface:
 
-  PMEMDevice / CostModel        — simulated PMEM with real volatility
-  VirtualTimeline               — per-resource modelled-time engine (§14)
+  PMEMDevice / DeviceStats      — simulated PMEM with real volatility
   persist / write_and_force     — persistence + replication primitives
   IntegrityRegion / AtomicRegion— integrity + atomicity primitives
   Log / LogConfig               — the log (reserve/copy/complete/force)
@@ -19,8 +18,7 @@ Public surface:
   baselines                     — PMDK / FLEX / Query Fresh comparators
 """
 
-from .pmem import CACHE_LINE, ATOM, CostModel, DeviceStats, PMEMDevice
-from .timeline import Interval, VirtualTimeline
+from .pmem import CACHE_LINE, ATOM, DeviceStats, PMEMDevice
 from .primitives import (AtomicRegion, ForceRound, IntegrityRegion, LF_REP,
                          ORDERINGS, PARALLEL, REP_LF, SalvageForceRound,
                          persist, reissue_segs, write_and_force,
@@ -48,8 +46,7 @@ from .router import (LogRouter, RouterError, RouterRecovery, Shard,
                      UnknownShardError, payload_digest, stream_digest)
 
 __all__ = [
-    "CACHE_LINE", "ATOM", "CostModel", "DeviceStats", "PMEMDevice",
-    "Interval", "VirtualTimeline",
+    "CACHE_LINE", "ATOM", "DeviceStats", "PMEMDevice",
     "AtomicRegion", "ForceRound", "IntegrityRegion", "LF_REP", "ORDERINGS",
     "PARALLEL", "REP_LF", "SalvageForceRound", "persist", "reissue_segs",
     "write_and_force", "write_and_force_segs", "write_and_force_segs_async",

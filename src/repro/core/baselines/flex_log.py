@@ -38,7 +38,7 @@ class FlexLog:
         dev.write(0, _HDR.pack(0, 0))
         dev.persist(0, _HDR.size)
 
-    def append(self, data: bytes) -> Tuple[int, float]:
+    def append(self, data: bytes) -> int:
         with self._lock:
             n = len(data)
             if self._tail + _REC.size + n > self.capacity:
@@ -46,19 +46,19 @@ class FlexLog:
             off = self.HEADER + self._tail
             lsn = self._count + 1
             # operation 1: header (own persist)
-            vns = self.dev.write(off, _REC.pack(lsn, n, zlib.crc32(data)))
-            vns += self.dev.persist(off, _REC.size)
+            self.dev.write(off, _REC.pack(lsn, n, zlib.crc32(data)))
+            self.dev.persist(off, _REC.size)
             # operation 2: payload (own persist)
-            vns += self.dev.write(off + _REC.size, data)
-            vns += self.dev.persist(off + _REC.size, n)
+            self.dev.write(off + _REC.size, data)
+            self.dev.persist(off + _REC.size, n)
             self._tail += _REC.size + n
             self._count = lsn
             # operation 3: tail pointer
-            vns += self.dev.write(0, _HDR.pack(self._tail, self._count))
-            vns += self.dev.persist(0, _HDR.size)
-            return lsn, vns
+            self.dev.write(0, _HDR.pack(self._tail, self._count))
+            self.dev.persist(0, _HDR.size)
+            return lsn
 
-    def append_batch(self, payloads: List[bytes]) -> Tuple[List[int], float]:
+    def append_batch(self, payloads: List[bytes]) -> List[int]:
         return append_batch_looped(self, payloads)
 
     def iter_records(self) -> Iterator[Tuple[int, bytes]]:

@@ -36,22 +36,22 @@ class PMDKLog:
         dev.write(0, _HDR.pack(0, 0))
         dev.persist(0, _HDR.size)
 
-    def append(self, data: bytes) -> Tuple[int, float]:
+    def append(self, data: bytes) -> int:
         with self._lock:                      # coarse isolation
             n = len(data)
             if self._tail + 8 + n > self.capacity:
                 raise RuntimeError("pmemlog full")
             off = self.HEADER + self._tail
-            vns = self.dev.write(off, struct.pack("<Q", n))
-            vns += self.dev.write(off + 8, data)
-            vns += self.dev.persist(off, 8 + n)          # flush payload
+            self.dev.write(off, struct.pack("<Q", n))
+            self.dev.write(off + 8, data)
+            self.dev.persist(off, 8 + n)                 # flush payload
             self._tail += 8 + n
             self._count += 1
-            vns += self.dev.write(0, _HDR.pack(self._tail, self._count))
-            vns += self.dev.persist(0, _HDR.size)        # flush tail ptr
-            return self._count, vns
+            self.dev.write(0, _HDR.pack(self._tail, self._count))
+            self.dev.persist(0, _HDR.size)               # flush tail ptr
+            return self._count
 
-    def append_batch(self, payloads: List[bytes]) -> Tuple[List[int], float]:
+    def append_batch(self, payloads: List[bytes]) -> List[int]:
         return append_batch_looped(self, payloads)
 
     def iter_records(self) -> Iterator[Tuple[int, bytes]]:

@@ -45,45 +45,43 @@ class QueryFreshLog:
         dev.write(0, _HDR.pack(0, 0))
         dev.persist(0, _HDR.size)
 
-    def append(self, data: bytes) -> Tuple[int, float]:
+    def append(self, data: bytes) -> int:
         with self._lock:          # coarse lock: append + window bookkeeping
             n = len(data)
             if self._tail + _REC.size + n > self.capacity:
                 raise RuntimeError("query-fresh log full")
             off = self.HEADER + self._tail
             lsn = self._count + 1
-            vns = self.dev.write(off, _REC.pack(lsn, n))
-            vns += self.dev.write(off + _REC.size, data)
+            self.dev.write(off, _REC.pack(lsn, n))
+            self.dev.write(off + _REC.size, data)
             self._tail += _REC.size + n
             self._count = lsn
             self._window += 1
             if self._window >= self.group_size:
                 self._window = 0
-                vns += self._ship_locked()
-            return lsn, vns
+                self._ship_locked()
+            return lsn
 
-    def append_batch(self, payloads: List[bytes]) -> Tuple[List[int], float]:
+    def append_batch(self, payloads: List[bytes]) -> List[int]:
         return append_batch_looped(self, payloads)
 
-    def flush(self) -> float:
+    def flush(self) -> None:
         with self._lock:
-            return self._ship_locked()
+            self._ship_locked()
 
-    def _ship_locked(self) -> float:
+    def _ship_locked(self) -> None:
         start, end = self._shipped, self._tail
         if end == start:
-            return 0.0
-        vns = self.dev.persist(self.HEADER + start, end - start)
+            return
+        self.dev.persist(self.HEADER + start, end - start)
         if self.repl is not None:
-            vns += self.repl.replicate(self.dev, self.HEADER + start,
-                                       self.HEADER + start, end - start)
-        vns += self.dev.write(0, _HDR.pack(self._tail, self._count))
-        vns += self.dev.persist(0, _HDR.size)
+            self.repl.replicate(self.dev, self.HEADER + start,
+                                self.HEADER + start, end - start)
+        self.dev.write(0, _HDR.pack(self._tail, self._count))
+        self.dev.persist(0, _HDR.size)
         if self.repl is not None:
-            vns += self.repl.broadcast_bytes(
-                self.dev.read(0, _HDR.size), 0)
+            self.repl.broadcast_bytes(self.dev.read(0, _HDR.size), 0)
         self._shipped = end
-        return vns
 
     def iter_records(self) -> Iterator[Tuple[int, bytes]]:
         tail, count = _HDR.unpack(self.dev.read(0, _HDR.size))

@@ -66,17 +66,13 @@ class ForcePolicy:
         for lsn in lsns:
             self.on_complete(log, lsn)
 
-    def drain(self, log: Log) -> float:
+    def drain(self, log: Log) -> None:
         """Force everything reserved so far, wait for every in-flight
-        durability round to retire, and surface deferred round errors.
-        Returns the log's ``durable_vtime`` — the modelled time at which
-        the drained prefix became durable (DESIGN.md §14), so benchmark
-        loops read modelled latency from the same call that quiesces."""
+        durability round to retire, and surface deferred round errors."""
         last = log.next_lsn - 1
         if last >= 1 and log.durable_lsn < last:
             log.force(last, freq=1)
         log.drain()
-        return log.durable_vtime
 
     def _bound(self, log: Log, depth: int) -> Optional[int]:
         return None

@@ -11,10 +11,9 @@ unit-testable:
     path's batched CRC32/PHASH validation (`log._first_bad_payload`)
     and repairing from any clean quorum copy with the chunk-diff
     machinery (`recovery._diff_ranges`) so only the damaged cache-line
-    chunks travel.  A per-pass bandwidth budget (bytes and modelled
-    vns) plus a busy-backoff signal keep scrubbing from starving the
-    force pipeline; a resume cursor makes budgeted passes cover the
-    whole prefix round-robin.
+    chunks travel.  A per-pass byte budget plus a busy-backoff signal
+    keep scrubbing from starving the force pipeline; a resume cursor
+    makes budgeted passes cover the whole prefix round-robin.
 
   * ``resync_backup`` — online rejoin for a backup with a long gap
     (§4.2 backup rejoin, carried ROADMAP item): a catch-up phase
@@ -84,7 +83,6 @@ def _bad_ordinals(raw: bytes, items) -> set:
 class ScrubConfig:
     chunk: int = REPAIR_CHUNK          # repair granularity (cache-line mult.)
     max_bytes_per_pass: Optional[int] = None   # scan budget across copies
-    max_vns_per_pass: Optional[float] = None   # modelled-time budget
     interval_s: float = 0.02           # thread-mode pass period
     defer_when_busy: bool = True       # skip a pass while the engine is hot
 
@@ -104,9 +102,6 @@ class ScrubReport:
                                        # between snapshot and repair
     repair_bytes: int = 0              # chunk-diff traffic shipped
     repair_ranges: int = 0
-    vns: float = 0.0                   # scan_vns + repair_vns (compat)
-    scan_vns: float = 0.0              # modelled read+checksum time
-    repair_vns: float = 0.0            # modelled repair-traffic time
     corrupt_records: List[Tuple[str, int]] = field(default_factory=list)
     total_records: int = 0             # committed records in the snapshot
 
@@ -117,10 +112,9 @@ class Scrubber:
     ``copies`` maps replica name → device holding a full log image (the
     node-local scrub agent's view of its own media).  Detection reads and
     checksums are local to each node; repair ships only the differing
-    chunks of a clean donor copy over the repair channel, charged at RDMA
-    rates in ``vns``.  Built over a ``ReplicaSet`` via
-    :meth:`from_replica_set`, the copy set tracks lane liveness so dead
-    backups are neither scanned nor used as donors.
+    chunks of a clean donor copy over the repair channel.  Built over a
+    ``ReplicaSet`` via :meth:`from_replica_set`, the copy set tracks lane
+    liveness so dead backups are neither scanned nor used as donors.
     """
 
     def __init__(self, log, copies: Optional[Dict[str, PMEMDevice]] = None,
@@ -146,9 +140,6 @@ class Scrubber:
         self.unrepairable_total = 0
         self.skipped_trimmed_total = 0
         self.repair_bytes_total = 0
-        self.vns_total = 0.0           # scan + repair (compat)
-        self.scan_vns_total = 0.0
-        self.repair_vns_total = 0.0
 
     # -- construction ------------------------------------------------------ #
     @classmethod
@@ -207,7 +198,6 @@ class Scrubber:
         if not copies:
             rep.complete = True
             return rep
-        cost = log.dev.cost
         # snapshot the committed record map: lock order matches cleanup
         # (_alloc_lock outer, _commit_cv inner).  Committed == lsn <=
         # durable_lsn: the covering round met its write quorum, so a
@@ -233,25 +223,17 @@ class Scrubber:
                 break
         order = recs[i0:] + recs[:i0]
         budget_b = self.cfg.max_bytes_per_pass
-        budget_v = self.cfg.max_vns_per_pass
         n_copies = len(copies)
         scanned: List[Tuple[int, int, int, int]] = []
         for rec in order:
             extent = rec[3]
-            if scanned and (
-                    (budget_b is not None
-                     and rep.scanned_bytes + extent * n_copies > budget_b)
-                    or (budget_v is not None
-                        and rep.scan_vns >= budget_v)):
+            # the budget bounds the SCAN slice: repair traffic is
+            # corrective work a corrupt pass must finish regardless
+            if scanned and budget_b is not None \
+                    and rep.scanned_bytes + extent * n_copies > budget_b:
                 break
             scanned.append(rec)
             rep.scanned_bytes += extent * n_copies
-            # the vns budget bounds the SCAN slice: repair traffic is
-            # corrective work a corrupt pass must finish regardless, and
-            # counting it against the budget used to shrink coverage of
-            # exactly the passes that found damage (PR 10 satellite)
-            rep.scan_vns += extent * n_copies \
-                * (cost.pmem_read_byte_ns + cost.crc_byte_ns)
         rep.complete = len(scanned) == len(recs)
         self._cursor = 1 if rep.complete else \
             (scanned[-1][0] + 1 if scanned else self._cursor)
@@ -318,8 +300,6 @@ class Scrubber:
                         dev.persist(a, b - a)
                         rep.repair_bytes += b - a
                         rep.repair_ranges += 1
-                        rep.repair_vns += cost.rdma_rtt_ns \
-                            + (b - a) * cost.rdma_byte_ns
                     # read back and re-validate before declaring it fixed
                     raw = dev.read(off, extent)
                     hl, hs, hc, hf = _REC_HDR.unpack_from(raw, 0)
@@ -332,21 +312,11 @@ class Scrubber:
                         rep.repaired += 1
                     else:
                         rep.unrepairable += 1
-        rep.vns = rep.scan_vns + rep.repair_vns
         self.scanned_bytes_total += rep.scanned_bytes
         self.corrupt_total += rep.corrupt
         self.repaired_total += rep.repaired
         self.unrepairable_total += rep.unrepairable
         self.repair_bytes_total += rep.repair_bytes
-        self.scan_vns_total += rep.scan_vns
-        self.repair_vns_total += rep.repair_vns
-        self.vns_total += rep.vns
-        # background work rides the log's virtual timeline on its own
-        # resource: scan reads occupy scrub bandwidth, repair traffic is
-        # wire latency on top (DESIGN.md §14)
-        tl = getattr(log, "timeline", None)
-        if tl is not None and rep.vns:
-            tl.schedule("scrub", busy=rep.scan_vns, latency=rep.repair_vns)
         return rep
 
     def scrub_to_completion(self, max_passes: int = 64) -> List[ScrubReport]:
@@ -406,10 +376,7 @@ class Scrubber:
                     repaired=self.repaired_total,
                     unrepairable=self.unrepairable_total,
                     skipped_trimmed=self.skipped_trimmed_total,
-                    repair_bytes=self.repair_bytes_total,
-                    scrub_vns=self.vns_total,
-                    scan_vns=self.scan_vns_total,
-                    repair_vns=self.repair_vns_total)
+                    repair_bytes=self.repair_bytes_total)
 
 
 # --------------------------------------------------------------------------- #
@@ -424,7 +391,6 @@ class ResyncReport:
     catchup_bytes: int = 0     # differing chunks actually shipped
     catchup_ranges: int = 0
     cutover_bytes: int = 0     # issued-but-unsealed delta under _issue_lock
-    vns: float = 0.0
 
     @property
     def repair_bytes(self) -> int:
@@ -465,7 +431,6 @@ def resync_backup(rs, server_id: str,
              if tr.server.server_id == server_id)
     srv = t.server
     backup = srv.device
-    cost = backup.cost
     rep = ResyncReport(server_id=server_id)
     # phase 0: quiesce + detach
     if rs.group is not None:
@@ -481,7 +446,6 @@ def resync_backup(rs, server_id: str,
         golden = log.dev.read(off, n)
         cur = backup.read(off, n)
         rep.sealed_bytes += n
-        rep.vns += 2 * n * cost.pmem_read_byte_ns
         if golden == cur:
             continue
         gold_np = np.frombuffer(golden, dtype=np.uint8)
@@ -491,7 +455,6 @@ def resync_backup(rs, server_id: str,
             backup.persist(a, b - a)
             rep.catchup_bytes += b - a
             rep.catchup_ranges += 1
-            rep.vns += cost.rdma_rtt_ns + (b - a) * cost.rdma_byte_ns
     # phase 2: cut-over under the doorbell lock
     with log._issue_lock:
         with log._commit_cv:
@@ -501,7 +464,6 @@ def resync_backup(rs, server_id: str,
             backup.write(off, data)
             backup.persist(off, n)
             rep.cutover_bytes += n
-            rep.vns += cost.rdma_rtt_ns + n * cost.rdma_byte_ns
         # a trim during catch-up advanced the watermark slot and
         # superline while this lane was closed; Log.trim holds
         # _issue_lock for its whole body, so re-diffing the meta
@@ -516,7 +478,6 @@ def resync_backup(rs, server_id: str,
                 backup.write(a, meta_gold[a:b])
                 backup.persist(a, b - a)
                 rep.cutover_bytes += b - a
-                rep.vns += cost.rdma_rtt_ns + (b - a) * cost.rdma_byte_ns
         t.reopen()
         # re-admit only THIS path's primary: a ClusterManager epoch
         # fence of a deposed primary must stay up

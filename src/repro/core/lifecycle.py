@@ -61,7 +61,6 @@ class TrimReport:
     reclaimed_records: int
     trigger: str                  # "manual" | "space_low" | "log_full"
     wall_s: float
-    vns: float = 0.0
 
 
 class LogLifecycle:

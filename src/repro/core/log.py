@@ -6,7 +6,7 @@ path is split into four stages (Table 2) so that only the stages that
 
   reserve   — serialized: allocates ring space and the monotonic LSN.
   copy      — concurrent: writes payload bytes (direct PMEM pointer in
-              fast mode, non-temporal-store cost model).
+              fast mode, a device store in strict mode).
   complete  — concurrent: computes the payload CRC, publishes the record
               header (valid flag), advances the contiguous-complete
               watermark.
@@ -58,7 +58,6 @@ from .. import obs
 from .pmem import PMEMDevice
 from .primitives import (AtomicRegion, ForceRound, REP_LF, reissue_segs,
                          write_and_force, write_and_force_segs_async)
-from .timeline import VirtualTimeline
 from .transport import (QuorumError, ReplicationGroup, RoundSalvage,
                         TransportError)
 
@@ -283,9 +282,6 @@ class _PipeRound:
     salvage_src: Optional[List[_SalvageSeg]] = None
     gen: int = 0          # salvage generation at issue (tombstone guard)
     issued_at: float = 0.0  # monotonic issue stamp (ack-rate estimator)
-    vt_after: float = 0.0   # virtual-time dependency horizon: this round
-                            # cannot start before the round that vacated
-                            # its pipeline slot ended (DESIGN.md §14)
 
 
 @dataclass(slots=True)
@@ -617,25 +613,6 @@ class Log:
         self.trimmed_bytes_total = 0
         self.rounds_retired = 0       # durability rounds retired
         self.round_wall_s = 0.0       # their summed issue-to-retire seconds
-        self.force_vns_total = 0.0    # accumulated modelled hardware WORK
-        # virtual-timeline modelled TIME (DESIGN.md §14): retired rounds
-        # are placed on per-resource clocks (cpu / flush / wire:<id>),
-        # so overlapped pipeline rounds overlap in modelled time instead
-        # of being charged as a serial sum.  force_vns_total stays the
-        # work integral (fig8's per-record cost basis); _durable_vtime
-        # is the monotone end of the latest retired round.
-        self.timeline = VirtualTimeline()
-        self._durable_vtime = 0.0
-        # ends of recently retired rounds, retirement order: round i's
-        # dependency horizon is the end of round i-depth (the round
-        # whose retirement vacated the slot i was issued into)
-        self._vt_tail: Deque[float] = deque(maxlen=cfg.pipeline_depth + 2)
-        # per-round modelled charge history, parallel to _ack_ends, so
-        # timed appends attribute to a waiter exactly the rounds that
-        # covered it (not whatever else retired concurrently)
-        self._ack_vns: List[float] = []
-        self._ack_vtime: List[float] = []
-        self._ack_base_vns = 0.0      # boundary round's charge (aged-out)
 
     # ------------------------------------------------------------------ #
     # lifecycle
@@ -662,10 +639,10 @@ class Log:
             log._recover_local()
         return log
 
-    def _write_superline(self) -> float:
+    def _write_superline(self) -> None:
         s = Superline(self._epoch, self._head_lsn, self._start_lsn,
                       self._head_off, self.cfg.capacity)
-        return self._super.atomic_write(s.pack().ljust(SUPERLINE_SIZE, b"\0"))
+        self._super.atomic_write(s.pack().ljust(SUPERLINE_SIZE, b"\0"))
 
     @staticmethod
     def _superline_score(raw: bytes) -> tuple:
@@ -817,27 +794,27 @@ class Log:
             return self._trim_lsn
 
     def _write_header(self, ring_off: int, lsn: int, size: int, crc: int,
-                      flags: int) -> float:
-        return self.dev.write(self._abs(ring_off),
-                              _REC_HDR.pack(lsn, size, crc, flags))
+                      flags: int) -> None:
+        self.dev.write(self._abs(ring_off),
+                       _REC_HDR.pack(lsn, size, crc, flags))
 
     def getLSN(self, rec_id: int) -> int:
         return rec_id
 
-    def copy(self, rec_id: int, data: bytes, at: int = 0) -> float:
+    def copy(self, rec_id: int, data: bytes, at: int = 0) -> None:
         """Concurrent: copy payload bytes into the reserved record
         (non-temporal-store path)."""
         with obs.span(obs.LOG_COPY):
             rec = self._recs[rec_id]
             if at + len(data) > rec.size:
                 raise ValueError("copy out of record bounds")
-            return self.dev.write(rec.off + REC_HDR_SIZE + at, data)
+            self.dev.write(rec.off + REC_HDR_SIZE + at, data)
 
     def _use_phash(self, size: int) -> bool:
         t = self.cfg.phash_threshold
         return t is not None and size >= t
 
-    def complete(self, rec_id: int) -> float:
+    def complete(self, rec_id: int) -> None:
         """Concurrent: checksum the payload and publish the valid header."""
         with obs.span(obs.LOG_COMPLETE):
             rec = self._recs[rec_id]
@@ -847,9 +824,8 @@ class Log:
             phash = self._use_phash(rec.size)
             crc = _rec_checksum(rec.lsn, rec.size, payload, phash)
             flags = FLAG_VALID | (FLAG_PHASH if phash else 0)
-            vns = self.dev.write(
+            self.dev.write(
                 rec.off, _REC_HDR.pack(rec.lsn, rec.size, crc, flags))
-            vns += self.dev.cost.crc_byte_ns * rec.size
             self._mark_complete(rec_id)
             if self._space_low_pending:
                 # the crossing record is committed now, so a sync
@@ -859,7 +835,6 @@ class Log:
                 # stops refires)
                 self._space_low_pending = False
                 self._fire_space_low()
-            return vns
 
     def _mark_complete(self, rec_id: int) -> None:
         with self._commit_cv:
@@ -935,21 +910,15 @@ class Log:
     # bulk trim made deep head movement routine (PR 9 satellite).
     _ACK_LOG_CAP = 1 << 15
 
-    def _record_ack_locked(self, end_lsn: int, now: float,
-                           vns: float = 0.0, vtime: float = 0.0) -> None:
+    def _record_ack_locked(self, end_lsn: int, now: float) -> None:
         self._ack_ends.append(end_lsn)
         self._ack_wall.append(now)
-        self._ack_vns.append(vns)
-        self._ack_vtime.append(vtime)
         if len(self._ack_ends) > self._ACK_LOG_CAP:
             drop = self._ACK_LOG_CAP // 2
             self._ack_base = self._ack_ends[drop - 1]
             self._ack_base_wall = self._ack_wall[drop - 1]
-            self._ack_base_vns = self._ack_vns[drop - 1]
             del self._ack_ends[:drop]
             del self._ack_wall[:drop]
-            del self._ack_vns[:drop]
-            del self._ack_vtime[:drop]
 
     def durable_ack_time(self, lsn: int) -> Optional[float]:
         """The wall moment (time.monotonic domain) the round covering
@@ -962,17 +931,13 @@ class Log:
             return self._ack_time_locked(lsn)
 
     def _ack_time_locked(self, lsn: int) -> Optional[float]:
-        if lsn > self._durable_lsn:
+        i = self._round_index_locked(lsn)
+        if i is None:
             return None
-        if lsn <= self._ack_base:
-            # aged out (or recovered): the boundary stamp bounds the
-            # true retire moment from above; None only when the record
-            # predates this process entirely
-            return self._ack_base_wall
-        i = bisect_left(self._ack_ends, lsn)
-        if i == len(self._ack_ends):
-            return None
-        return self._ack_wall[i]
+        # aged out (or recovered): the boundary stamp bounds the true
+        # retire moment from above; None only when the record predates
+        # this process entirely
+        return self._ack_base_wall if i < 0 else self._ack_wall[i]
 
     def durable_ack_times(self, lsns: List[int]) -> List[Optional[float]]:
         """Bulk durable_ack_time: one lock acquisition for a whole wave
@@ -993,35 +958,6 @@ class Log:
         if i == len(self._ack_ends):
             return None
         return i
-
-    def durable_round_vns(self, lsn: int) -> Optional[float]:
-        """Modelled work (vns) of the ONE durability round that covered
-        ``lsn`` — the per-waiter attribution timed appends use instead
-        of a ``force_vns_total`` delta, which raced with every
-        concurrent leader's and salvage retry's charge.  For an LSN that
-        aged out of the bounded history, the boundary round's charge (an
-        arbitrary but harmless stand-in: timed appends read this within
-        a round-trip of their own force).  None if not durable yet."""
-        with self._commit_cv:
-            i = self._round_index_locked(lsn)
-            if i is None:
-                return None
-            return self._ack_base_vns if i < 0 else self._ack_vns[i]
-
-    def durable_rounds_vns(self, lsns: List[int]) -> float:
-        """Summed modelled work of the DISTINCT rounds covering ``lsns``
-        (a batch whose members rode one round is charged that round
-        once).  Not-yet-durable members contribute nothing."""
-        with self._commit_cv:
-            seen = set()
-            total = 0.0
-            for lsn in lsns:
-                i = self._round_index_locked(lsn)
-                if i is None or i in seen:
-                    continue
-                seen.add(i)
-                total += self._ack_base_vns if i < 0 else self._ack_vns[i]
-            return total
 
     # a flapping backup can oscillate the controller indefinitely; the
     # trajectory is an observability aid, not a ledger — cap it
@@ -1190,13 +1126,6 @@ class Log:
                     entry = _PipeRound(lsn, start_off, end_off,
                                        gen=self._salvage_gen,
                                        issued_at=time.monotonic())
-                # timeline slot dependency (DESIGN.md §14): with k rounds
-                # still in flight this round occupies the slot vacated by
-                # the (depth - k)-th most recently retired round, whose
-                # end is in _vt_tail (the slot wait above guarantees
-                # k < depth, so that round has retired)
-                rel = len(self._vt_tail) + len(self._inflight) - self._depth
-                entry.vt_after = self._vt_tail[rel] if rel >= 0 else 0.0
                 issue_span.set_metadata(round=entry.end_lsn)
                 self._inflight.append(entry)
                 self._issue_lsn = entry.end_lsn
@@ -1245,7 +1174,7 @@ class Log:
                 if entry.handle is None or not entry.handle.done():
                     break
                 try:
-                    vns = entry.handle.wait(timeout=0)
+                    entry.handle.wait(timeout=0)
                 except Exception as exc:
                     # KeyboardInterrupt/SystemExit must propagate to the
                     # settling thread, not poison the pipeline as a
@@ -1257,21 +1186,11 @@ class Log:
                     now = time.monotonic()
                     self._durable_lsn = entry.end_lsn
                     self._durable_off = entry.end_off % self.cfg.capacity
-                    self.force_vns_total += vns
-                    # place the round on the virtual timeline: its modelled
-                    # completion is the max over its resource intervals, not
-                    # the scalar sum — overlapped rounds now overlap in
-                    # modelled time (DESIGN.md §14)
-                    vt_end = entry.handle.schedule_on(self.timeline,
-                                                      entry.vt_after)
-                    if vt_end > self._durable_vtime:
-                        self._durable_vtime = vt_end
-                    self._vt_tail.append(vt_end)
                     self._clean_retires += 1
                     self.rounds_retired += 1
                     self.round_wall_s += now - entry.issued_at
                     self._ack_est.observe_retire(now, entry.issued_at)
-                    self._record_ack_locked(entry.end_lsn, now, vns, vt_end)
+                    self._record_ack_locked(entry.end_lsn, now)
                     if entry.salvage_src:
                         # the salvaged ranges reached their write quorum after
                         # all: durability was achieved, so the failures that
@@ -1540,31 +1459,6 @@ class Log:
         self.force(rec_id, freq=freq)
         return rec_id
 
-    def append_timed(self, data: bytes, freq: int = 1,
-                     per_record: bool = False):
-        """append + modelled hardware ns (benchmark instrumentation).
-
-        With ``per_record=True`` also returns the record's durable-ack
-        wall timestamp (``durable_ack_time``; None while a freq policy
-        left it unforced) as a third element."""
-        rec_id, view = self.reserve(len(data))
-        vns = 0.0
-        if view is not None:
-            view[:] = data
-            vns += self.dev.cost.store_byte_ns * len(data)
-        else:
-            vns += self.copy(rec_id, data)
-        vns += self.complete(rec_id)
-        self.force(rec_id, freq=freq)
-        # charge exactly the round that covered this record — a
-        # force_vns_total delta across the unlocked force would also
-        # bill every concurrent leader's round and salvage retry to
-        # this caller (PR 10 satellite)
-        vns += self.durable_round_vns(rec_id) or 0.0
-        if per_record:
-            return rec_id, vns, self.durable_ack_time(rec_id)
-        return rec_id, vns
-
     # ------------------------------------------------------------------ #
     # batched write path (DESIGN.md §3)
     # ------------------------------------------------------------------ #
@@ -1663,24 +1557,21 @@ class Log:
         self._used = used
         return self._space_low_check_locked()
 
-    def copy_batch(self, batch: Batch, payloads: List[bytes]) -> float:
-        """Concurrent: stage all payload bytes (ntstore cost model)."""
+    def copy_batch(self, batch: Batch, payloads: List[bytes]) -> None:
+        """Concurrent: stage all payload bytes in the batch's buffers."""
         with obs.span(obs.LOG_COPY):
             if len(payloads) != len(batch.lsns):
                 raise ValueError(
                     f"batch holds {len(batch.lsns)} records, got "
                     f"{len(payloads)} payloads")
-            total = 0
             for i, data in enumerate(payloads):
                 rec, seg_idx, pay_off = batch._items[i]
                 if len(data) > rec.size:
                     raise ValueError("copy out of record bounds")
                 buf = batch._segs[seg_idx].buf
                 buf[pay_off : pay_off + len(data)] = data
-                total += len(data)
-            return self.dev.cost.store_byte_ns * total
 
-    def complete_batch(self, batch: Batch) -> float:
+    def complete_batch(self, batch: Batch) -> None:
         """Concurrent: checksum every payload in one sweep, pack all
         headers, publish each staged segment with ONE device write, and
         advance the complete watermark with ONE _commit_cv pass."""
@@ -1688,8 +1579,6 @@ class Log:
             if batch._completed:
                 raise LogError("batch already completed")
             batch._completed = True
-            vns = 0.0
-            crc_bytes = 0
             views = [memoryview(seg.buf) for seg in batch._segs]
             pack, threshold = _REC_HDR.pack, self.cfg.phash_threshold
             for rec, seg_idx, pay_off in batch._items:
@@ -1701,15 +1590,12 @@ class Log:
                 flags = FLAG_VALID | (FLAG_PHASH if phash else 0)
                 mv[pay_off - REC_HDR_SIZE : pay_off] = pack(
                     rec.lsn, size, crc, flags)
-                crc_bytes += size
             for seg in batch._segs:
-                vns += self.dev.write(self._abs(seg.ring_off), seg.buf)
-            vns += self.dev.cost.crc_byte_ns * crc_bytes
+                self.dev.write(self._abs(seg.ring_off), seg.buf)
             self._mark_complete_many(batch._pad_lsns + batch.lsns)
             if self._space_low_pending:
                 self._space_low_pending = False
                 self._fire_space_low()
-            return vns
 
     def force_batch(self, batch: Batch, freq: int = 1,
                     timeout: Optional[float] = None,
@@ -1740,52 +1626,11 @@ class Log:
         self.force_batch(batch, freq=freq)
         return batch.lsns
 
-    def append_batch_timed(self, payloads: List[bytes], freq: int = 1,
-                           per_record: bool = False):
-        """append_batch + modelled hardware ns (benchmark instrumentation).
-
-        With ``per_record=True`` also returns one durable-ack wall
-        timestamp PER RECORD (``durable_ack_time``) as a third element:
-        each member is stamped with the retirement of its own covering
-        round, not a batch average — members that landed in different
-        pipeline rounds carry different stamps, and members a freq
-        policy left unforced carry None.  This is what makes batch p99
-        claims record-level truth."""
-        batch = self.reserve_batch([len(p) for p in payloads])
-        vns = self.copy_batch(batch, payloads)
-        vns += self.complete_batch(batch)
-        self.force_batch(batch, freq=freq)
-        # sum the DISTINCT rounds that covered the batch's members (not
-        # a force_vns_total delta, which raced with concurrent leaders)
-        vns += self.durable_rounds_vns(batch.lsns)
-        if per_record:
-            return batch.lsns, vns, \
-                [self.durable_ack_time(l) for l in batch.lsns]
-        return batch.lsns, vns
-
     # observability ------------------------------------------------------ #
     @property
     def durable_lsn(self) -> int:
         with self._commit_cv:
             return self._durable_lsn
-
-    @property
-    def durable_vtime(self) -> float:
-        """Modelled vtime (vns) at which the latest retired round ended
-        on the virtual timeline — the log's modelled durability *time*.
-        Monotone; equals ``force_vns_total`` exactly when rounds never
-        overlap (blocking forces at pipeline depth 1), and falls below
-        it by the overlap the pipeline achieves (DESIGN.md §14)."""
-        with self._commit_cv:
-            return self._durable_vtime
-
-    def modelled_time_ns(self) -> float:
-        """Modelled wall clock of everything charged to this log's
-        timeline: durability rounds plus background work (scrub reads)
-        scheduled on other resources."""
-        with self._commit_cv:
-            dv = self._durable_vtime
-        return max(dv, self.timeline.makespan())
 
     @property
     def completed_lsn(self) -> int:
@@ -1825,7 +1670,7 @@ class Log:
         return _trim_decode(self.dev.read(self.trim_off, TRIM_SLOT_SIZE))
 
     def trim(self, upto_lsn: int,
-             _crash_hook=None) -> float:
+             _crash_hook=None) -> None:
         """Bulk truncate: reclaim every record at or below ``upto_lsn``
         (DESIGN.md §13).
 
@@ -1862,7 +1707,7 @@ class Log:
                         f"{self._durable_lsn}: un-acked records cannot "
                         f"be declared checkpointed")
                 if upto_lsn < self._head_lsn:
-                    return 0.0
+                    return
                 nxt = self._recs.get(upto_lsn + 1)
                 new_head_off = (nxt.off - self.ring_off) if nxt is not None \
                     else self._tail_off
@@ -1871,11 +1716,11 @@ class Log:
             #    above the durable watermark, so they are disjoint from
             #    everything this trim touches — no exclusion needed.
             hook("pre_watermark")
-            vns = self.dev.write(self.trim_off, _trim_encode(upto_lsn))
+            self.dev.write(self.trim_off, _trim_encode(upto_lsn))
             hook("pre_watermark_flush")
-            vns += write_and_force(self.dev, self.trim_off, TRIM_SLOT_SIZE,
-                                   self.repl, self.cfg.ordering,
-                                   local_durable=self.cfg.local_durable)
+            write_and_force(self.dev, self.trim_off, TRIM_SLOT_SIZE,
+                            self.repl, self.cfg.ordering,
+                            local_durable=self.cfg.local_durable)
             hook("post_watermark")
             # 2) O(1) device bookkeeping: drop the volatile entries and
             #    advance the head over the whole span at once
@@ -1899,17 +1744,16 @@ class Log:
             # 3) publish the advanced head (two-copy atomic superline,
             #    replicated) — pure acceleration: recovery adopts the
             #    post-trim view from the watermark alone
-            vns += self._write_superline()
+            self._write_superline()
             hook("post_superline")
-        return vns
 
-    def cleanup(self, rec_id: int) -> float:
+    def cleanup(self, rec_id: int) -> None:
         """Tombstone one record; advance the head over any contiguous
         reclaimed prefix and publish it in the superline."""
         with self._alloc_lock:
             rec = self._recs.get(rec_id)
             if rec is None:
-                return 0.0
+                return
             with self._commit_cv:
                 # Salvage stash segments and staged wire images only ever
                 # cover ranges ABOVE the durable watermark, so tombstoning
@@ -1923,7 +1767,8 @@ class Log:
                 # the durable-record path never pays it).
                 guard = rec.lsn > self._durable_lsn
             if not guard:
-                return self._cleanup_rec_locked(rec)
+                self._cleanup_rec_locked(rec)
+                return
             with self._issue_lock:
                 with self._commit_cv:
                     # drop the stash and bump the generation so a round
@@ -1931,18 +1776,18 @@ class Log:
                     # when it fails later (full fresh re-issue instead)
                     self._salvage.clear()
                     self._salvage_gen += 1
-                return self._cleanup_rec_locked(rec)
+                self._cleanup_rec_locked(rec)
 
-    def _cleanup_rec_locked(self, rec: _Rec) -> float:
+    def _cleanup_rec_locked(self, rec: _Rec) -> None:
         """Tombstone body; caller holds _alloc_lock (+ _issue_lock when
         the record may sit inside a salvage/staged range)."""
         raw = self.dev.read(rec.off, REC_HDR_SIZE)
         lsn, size, crc, flags = _REC_HDR.unpack(raw)
-        vns = self.dev.write(rec.off, _REC_HDR.pack(
+        self.dev.write(rec.off, _REC_HDR.pack(
             lsn, size, crc, (flags | FLAG_CLEANED) & ~FLAG_VALID))
-        vns += write_and_force(self.dev, rec.off, REC_HDR_SIZE, self.repl,
-                               self.cfg.ordering,
-                               local_durable=self.cfg.local_durable)
+        write_and_force(self.dev, rec.off, REC_HDR_SIZE, self.repl,
+                        self.cfg.ordering,
+                        local_durable=self.cfg.local_durable)
         # advance head over contiguous cleaned/pad records
         advanced = False
         while True:
@@ -1962,10 +1807,9 @@ class Log:
             advanced = True
         if advanced:
             self._rearm_space_low_locked()
-            vns += self._write_superline()
-        return vns
+            self._write_superline()
 
-    def cleanupAll(self) -> float:
+    def cleanupAll(self) -> None:
         """Reinitialize the whole log, preserving the epoch (§4.3)."""
         with self._alloc_lock, self._commit_cv:
             self._recs.clear()
@@ -1981,7 +1825,7 @@ class Log:
             self._issue_lsn = self._durable_lsn
             self._issue_off = 0
             self._rearm_space_low_locked()
-            return self._write_superline()
+            self._write_superline()
 
     # ------------------------------------------------------------------ #
     # recovery (local copy) — vectorized scan (DESIGN.md §5)
@@ -2319,6 +2163,4 @@ class Log:
                         salvage_spilled_images=self.salvage_spilled_images,
                         depth_bdp=self._ack_est.bdp_rounds(),
                         rounds_retired=self.rounds_retired,
-                        round_wall_s=self.round_wall_s,
-                        force_vns_total=self.force_vns_total,
-                        durable_vtime=self._durable_vtime)
+                        round_wall_s=self.round_wall_s)

@@ -18,8 +18,8 @@ PMEM (Optane DCPMM behind the x86 cache hierarchy):
                   (torn + reordered writes).  Used by correctness tests.
   * ``fast``    — writes go straight to a NumPy buffer (a write-through
                   view of the same semantics: everything a crash *may*
-                  persist).  Used by benchmarks where we measure real
-                  software cost (copies, checksums, locking).
+                  persist).  Used where only the software cost (copies,
+                  checksums, locking) matters.
 
 The strict model is vectorized (DESIGN.md §1): instead of a dict of
 8-byte unit blobs and Python sets of line numbers, the device keeps
@@ -36,12 +36,9 @@ what makes ``crash()`` an independent keep/drop draw per unit — the same
 torn/reordered semantics the scalar model realized one dict entry at a
 time.
 
-Because this container has no Optane or RDMA NIC, hardware wait times are
-accounted in *virtual nanoseconds* via ``CostModel``: every operation
-returns the modelled ns it would take on the paper's testbed (Cascade
-Lake + DCPMM + EDR InfiniBand).  Real compute (memcpy, CRC) is measured
-with the wall clock and folded into the same figure.  Benchmarks report
-both clocks; see DESIGN.md §2.3.
+``DeviceStats`` counts the hardware events the paper reads with PCM
+(stores, flushes, lines written back, fences, LLC hits and misses of
+NIC reads); the flush-economy claims are pinned on these counts.
 """
 
 from __future__ import annotations
@@ -54,44 +51,6 @@ import numpy as np
 
 CACHE_LINE = 64  # bytes, x86
 ATOM = 8         # PMEM atomic persist unit, bytes
-
-
-@dataclass
-class CostModel:
-    """Virtual-time constants, calibrated to the paper's testbed numbers.
-
-    Defaults give: 1KB local persist ~ 1.1us, 1KB replicated write ~ 4.5us
-    (one round trip), matching the magnitudes in Fig. 5b / Fig. 6.
-
-    These constants price individual operations; *composition* of the
-    prices into modelled latency is the virtual-timeline engine's job
-    (``timeline.VirtualTimeline``, DESIGN.md §14): overlapped durability
-    rounds are laid out on per-resource clocks, so the modelled time of
-    a pipelined run is a timeline max, not a serial sum of these costs.
-    DeviceStats counters are independent of the constants — swapping a
-    cost model never moves a pinned hardware-event count or digest.
-    """
-
-    fence_ns: float = 100.0           # sfence drain
-    line_writeback_ns: float = 60.0   # clwb per dirty line (async, overlapped)
-    store_byte_ns: float = 0.12       # ntstore bandwidth ~ 8 GB/s
-    pmem_read_byte_ns: float = 0.06   # PMEM read bandwidth ~ 16 GB/s
-    rdma_rtt_ns: float = 3000.0       # EDR IB small-message round trip
-    rdma_byte_ns: float = 0.085       # ~ 11.7 GB/s effective wire bandwidth
-    llc_miss_ns: float = 80.0         # NIC DMA read that misses LLC (per line)
-    crc_byte_ns: float = 0.25         # crc32 software cost (accounted, not spun)
-    doorbell_ns: float = 150.0        # WQE post + doorbell ring (issue gap)
-
-    def with_wire_rtt(self, rtt_ns: float) -> "CostModel":
-        """This model with a different wire round trip — the what-if
-        knob the timeline engine makes meaningful: a far-memory / CXL
-        fabric (PAPERS.md, "Rethinking PM Crash Consistency in the CXL
-        Era") or an injected-latency testbed is the same hardware with a
-        slower wire, and only the modelled *time* should move, never the
-        DeviceStats.  fig6 uses this to model its injected wall-clock
-        RTT honestly instead of pricing a 4 ms stall at 3 us."""
-        from dataclasses import replace
-        return replace(self, rdma_rtt_ns=float(rtt_ns))
 
 
 @dataclass
@@ -118,14 +77,12 @@ class PMEMDevice:
         self,
         size: int,
         mode: str = "fast",
-        cost: Optional[CostModel] = None,
         name: str = "pmem0",
     ):
         if mode not in ("fast", "strict"):
             raise ValueError(f"unknown mode {mode!r}")
         self.size = int(size)
         self.mode = mode
-        self.cost = cost or CostModel()
         self.name = name
         self.stats = DeviceStats()
         self._lock = threading.Lock()
@@ -150,15 +107,16 @@ class PMEMDevice:
     # ------------------------------------------------------------------ #
     # store / load
     # ------------------------------------------------------------------ #
-    def write(self, off: int, data: bytes | bytearray | memoryview | np.ndarray) -> float:
-        """CPU stores to [off, off+len). Volatile until persisted. Returns vns."""
+    def write(self, off: int,
+              data: bytes | bytearray | memoryview | np.ndarray) -> None:
+        """CPU stores to [off, off+len). Volatile until persisted."""
         arr = _as_array(data)
         n = arr.size
         self._check(off, n)
         if n == 0:
             with self._lock:
                 self.stats.writes += 1
-            return 0.0
+            return
         if self.mode == "fast":
             self._durable[off : off + n] = arr
             with self._lock:
@@ -171,7 +129,6 @@ class PMEMDevice:
                 self.stats.writes += 1
                 self.stats.bytes_written += n
                 self._resident[off // CACHE_LINE : (off + n - 1) // CACHE_LINE + 1] = True
-        return self.cost.store_byte_ns * n
 
     def _write_strict_locked(self, off: int, arr: np.ndarray) -> None:
         """Store into the overlay at 8-byte-unit granularity.
@@ -226,8 +183,8 @@ class PMEMDevice:
     # ------------------------------------------------------------------ #
     # persistence primitive (clwb loop + sfence)
     # ------------------------------------------------------------------ #
-    def persist(self, off: int, n: int) -> float:
-        """Guarantee [off, off+n) is durable.  Returns vns (writeback+fence).
+    def persist(self, off: int, n: int) -> None:
+        """Guarantee [off, off+n) is durable (clwb per line + sfence).
 
         Evicts the lines from the cache model (see _resident note).  Every
         8-byte unit *overlapping* the range is persisted whole (a clwb
@@ -259,30 +216,20 @@ class PMEMDevice:
             self.stats.flushes += 1
             self.stats.lines_flushed += dirty_lines
             self.stats.fences += 1
-        # clwb writebacks overlap; fence waits for the slowest. Model as
-        # per-line issue cost + one fence drain.
-        return self.cost.line_writeback_ns * max(dirty_lines, 1) + self.cost.fence_ns
 
-    def dma_read(self, off: int, n: int) -> tuple[bytes, float]:
+    def dma_read(self, off: int, n: int) -> bytes:
         """Device-side (NIC) read of the *newest* data, as an RDMA HCA would
-        snoop it.  Cost depends on LLC residency: lines evicted by a prior
-        flush must be re-read from PMEM (the Fig. 6 effect)."""
+        snoop it.  Counts LLC residency: lines evicted by a prior flush
+        miss and must be re-read from PMEM (the Fig. 6 effect)."""
         data = self.read(off, n)
-        with self._lock:
-            if n > 0:
-                l0 = off // CACHE_LINE
-                l1 = (off + n - 1) // CACHE_LINE + 1
-                n_lines = l1 - l0
+        if n > 0:
+            l0 = off // CACHE_LINE
+            l1 = (off + n - 1) // CACHE_LINE + 1
+            with self._lock:
                 hit = int(np.count_nonzero(self._resident[l0:l1]))
-                miss = n_lines - hit
-            else:
-                n_lines = hit = miss = 0
-            self.stats.llc_misses += miss
-            self.stats.llc_hits += hit
-        vns = miss * self.cost.llc_miss_ns + n * self.cost.pmem_read_byte_ns * (
-            miss / max(n_lines, 1)
-        )
-        return data, vns
+                self.stats.llc_misses += l1 - l0 - hit
+                self.stats.llc_hits += hit
+        return data
 
     # ------------------------------------------------------------------ #
     # failure injection
@@ -297,8 +244,7 @@ class PMEMDevice:
         persistence* (later stores survive while earlier ones vanish).
         """
         rng = rng or np.random.default_rng(0)
-        survivor = PMEMDevice(self.size, mode=self.mode, cost=self.cost,
-                              name=self.name)
+        survivor = PMEMDevice(self.size, mode=self.mode, name=self.name)
         with self._lock:
             survivor._durable[:] = self._durable
             if self.mode == "strict" and self._dirty_count:

@@ -10,9 +10,6 @@
                  atomicity requirements (Listing 1 / Fig. 1).
   atomicity    — ``AtomicRegion``: copy-on-write double buffer + index
                  flip for fixed-location objects (Listing 2 / Fig. 2).
-
-Every mutating call returns virtual ns so benchmarks can report modelled
-hardware latency alongside measured software cost.
 """
 
 from __future__ import annotations
@@ -24,8 +21,7 @@ from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence, Tuple
 
 from .. import obs
-from .pmem import CostModel, PMEMDevice
-from .timeline import VirtualTimeline
+from .pmem import PMEMDevice
 from .transport import (QuorumError, QuorumRound, ReplicationGroup,
                         RoundSalvage)
 
@@ -38,9 +34,41 @@ REP_LF = "rep+lf"       # replicate first, then local flush (paper's winner)
 ORDERINGS = (PARALLEL, LF_REP, REP_LF)
 
 
-def persist(dev: PMEMDevice, off: int, n: int) -> float:
+def persist(dev: PMEMDevice, off: int, n: int) -> None:
     """Persistence primitive: make [off, off+n) durable on local PMEM."""
-    return dev.persist(off, n)
+    dev.persist(off, n)
+
+
+def _flush_first(ordering: str) -> bool:
+    """Whether the local flush runs before the replication post.  In
+    PARALLEL the two race, but the flush invalidates the LLC lines under
+    the NIC, so the DMA read sees the same evictions as LF+Rep (Fig. 6);
+    the model runs the flush first to reproduce them."""
+    if ordering == REP_LF:
+        return False
+    if ordering in (LF_REP, PARALLEL):
+        return True
+    raise ValueError(f"unknown ordering {ordering!r}")
+
+
+def _persist_segs(dev: PMEMDevice, segs, local_durable: bool) -> None:
+    if local_durable:
+        for off, n in segs:
+            dev.persist(off, n)
+
+
+def _local_only(dev: PMEMDevice, segs, repl: Optional[ReplicationGroup],
+                local_durable: bool) -> bool:
+    """Flush locally when no backup can take part; True if that was all
+    the work (raises QuorumError when the local copy cannot meet W)."""
+    if repl is not None and repl.live_transports():
+        return False
+    _persist_segs(dev, segs, local_durable)
+    if repl is not None \
+            and repl.write_quorum > (1 if repl.local_is_durable else 0):
+        raise QuorumError("no live backups and local copy alone cannot "
+                          f"meet W={repl.write_quorum}")
+    return True
 
 
 def write_and_force(
@@ -50,7 +78,7 @@ def write_and_force(
     repl: Optional[ReplicationGroup] = None,
     ordering: str = REP_LF,
     local_durable: bool = True,
-) -> float:
+) -> None:
     """Replication primitive: make [off, off+n) durable on a write quorum.
 
     ``dev`` holds the already-written bytes (volatile is fine — the NIC
@@ -58,34 +86,14 @@ def write_and_force(
     Fig. 6 study; REP_LF is the default because replicating first lets the
     NIC read source lines from LLC before the flush evicts them.
     """
-    if repl is None:
-        return dev.persist(off, n) if local_durable else 0.0
-    if not repl.live_transports():
-        vns = dev.persist(off, n) if local_durable else 0.0
-        if repl.write_quorum > (1 if repl.local_is_durable else 0):
-            raise QuorumError("no live backups and local copy alone cannot "
-                              f"meet W={repl.write_quorum}")
-        return vns
-
-    if ordering == REP_LF:
-        rep_vns = repl.replicate(dev, off, off, n, local_ack_vns=0.0)
-        loc_vns = dev.persist(off, n) if local_durable else 0.0
-        return rep_vns + loc_vns
-    if ordering == LF_REP:
-        loc_vns = dev.persist(off, n) if local_durable else 0.0
-        rep_vns = repl.replicate(dev, off, off, n, local_ack_vns=loc_vns)
-        return loc_vns + rep_vns
-    if ordering == PARALLEL:
-        # Flush and replication race, but the flush invalidates the LLC
-        # lines under the NIC, so the DMA read effectively serializes
-        # behind the writeback (same misses as LF+Rep) *plus* concurrent
-        # read/write contention on the DIMM — the paper measures parallel
-        # as the worst ordering (Fig. 6a/b).
-        loc_vns = dev.persist(off, n) if local_durable else 0.0
-        rep_vns = repl.replicate(dev, off, off, n, local_ack_vns=loc_vns)
-        contention = 0.1 * min(loc_vns, rep_vns)
-        return loc_vns + rep_vns + contention
-    raise ValueError(f"unknown ordering {ordering!r}")
+    if _local_only(dev, [(off, n)], repl, local_durable):
+        return
+    flush_first = _flush_first(ordering)
+    if flush_first:
+        _persist_segs(dev, [(off, n)], local_durable)
+    repl.replicate(dev, off, off, n)
+    if not flush_first:
+        _persist_segs(dev, [(off, n)], local_durable)
 
 
 def write_and_force_segs(
@@ -94,82 +102,43 @@ def write_and_force_segs(
     repl: Optional[ReplicationGroup] = None,
     ordering: str = REP_LF,
     local_durable: bool = True,
-) -> float:
+) -> None:
     """Replication primitive over a scatter list of (off, n) ranges.
 
     One doorbell-batched ``replicate_batch`` round covers every range —
     one wire round trip and one W-th-ack quorum wait for the whole list —
     while the local flushes run per range (the same clwb+sfence sequence
     the per-range path issues, so local DeviceStats are unchanged).  For
-    a single range this is cost- and stat-identical to write_and_force;
-    the log's force path uses it so a ring-wrap (two segments) no longer
-    pays two quorum rounds.
+    a single range this is stat-identical to write_and_force; the log's
+    force path uses it so a ring-wrap (two segments) no longer pays two
+    quorum rounds.
     """
     segs = [(off, n) for off, n in segs]
-    if not segs:
-        return 0.0
-    if len(segs) == 1 or repl is None or not repl.live_transports():
-        vns = 0.0
-        if repl is None:
-            for off, n in segs:
-                vns += dev.persist(off, n) if local_durable else 0.0
-            return vns
-        if not repl.live_transports():
-            for off, n in segs:
-                vns += dev.persist(off, n) if local_durable else 0.0
-            if repl.write_quorum > (1 if repl.local_is_durable else 0):
-                raise QuorumError("no live backups and local copy alone "
-                                  f"cannot meet W={repl.write_quorum}")
-            return vns
+    if not segs or _local_only(dev, segs, repl, local_durable):
+        return
+    if len(segs) == 1:
         off, n = segs[0]
-        return write_and_force(dev, off, n, repl, ordering,
-                               local_durable=local_durable)
-
-    def _persist_all() -> float:
-        if not local_durable:
-            return 0.0
-        return sum(dev.persist(off, n) for off, n in segs)
-
-    if ordering == REP_LF:
-        rep_vns = repl.replicate_batch(dev, segs, local_ack_vns=0.0)
-        return rep_vns + _persist_all()
-    if ordering == LF_REP:
-        loc_vns = _persist_all()
-        return loc_vns + repl.replicate_batch(dev, segs,
-                                              local_ack_vns=loc_vns)
-    if ordering == PARALLEL:
-        loc_vns = _persist_all()
-        rep_vns = repl.replicate_batch(dev, segs, local_ack_vns=loc_vns)
-        return loc_vns + rep_vns + 0.1 * min(loc_vns, rep_vns)
-    raise ValueError(f"unknown ordering {ordering!r}")
+        write_and_force(dev, off, n, repl, ordering,
+                        local_durable=local_durable)
+        return
+    flush_first = _flush_first(ordering)
+    if flush_first:
+        _persist_segs(dev, segs, local_durable)
+    repl.replicate_batch(dev, segs)
+    if not flush_first:
+        _persist_segs(dev, segs, local_durable)
 
 
 @dataclass
 class ForceRound:
     """Handle for one issued ``write_and_force_segs_async`` round.
 
-    ``wait()`` blocks until the round's write quorum settles and returns
-    the round's modelled cost.  Cost model (DESIGN.md §8-9): a round that
-    rides the async machinery pays the doorbell issue gap, and whatever
-    genuinely overlaps is charged as a max, not a sum —
-
-      REP_LF    max(wire, flush) + doorbell   — the flush runs after the
-                post and overlaps wire time; the post-time DMA snapshot
-                keeps the NIC's LLC hits.
-      LF_REP    flush + wire + doorbell       — the ordering *requires*
-                the flush to retire before the doorbell, so the serial
-                sum is the model, not an accounting artifact.
-      PARALLEL  max(wire, flush) + contention + doorbell — flush and wire
-                race; the engine orders the flush before the post only so
-                the DMA snapshot sees the same LLC evictions the real
-                race loses (Fig. 6), but latency-wise the two overlap,
-                plus the measured read/write DIMM contention penalty.
+    ``wait()`` blocks until the round's write quorum settles.  ``round``
+    is None when no wire work was needed (no replication group, or no
+    live backup): the local flush already ran, so the round is settled.
     """
 
-    round: Optional[QuorumRound]       # None => no wire work was needed
-    loc_vns: float = 0.0
-    issue_vns: float = 0.0
-    ordering: str = REP_LF
+    round: Optional[QuorumRound]
 
     def done(self) -> bool:
         return self.round is None or self.round.done()
@@ -187,63 +156,9 @@ class ForceRound:
             return []
         return [self.round.salvage()]
 
-    def wait(self, timeout: Optional[float] = None) -> float:
-        if self.round is None:
-            return self.loc_vns
-        rep_vns = self.round.result(timeout)
-        if self.ordering == REP_LF:
-            return max(rep_vns, self.loc_vns) + self.issue_vns
-        if self.ordering == LF_REP:
-            return self.loc_vns + rep_vns + self.issue_vns
-        return max(rep_vns, self.loc_vns) \
-            + 0.1 * min(self.loc_vns, rep_vns) + self.issue_vns
-
-    def schedule_on(self, tl: VirtualTimeline, after: float) -> float:
-        """Place this settled round on the virtual timeline and return its
-        modelled completion vtime (DESIGN.md §14).
-
-        ``after`` is the round's dependency horizon (its pipeline slot
-        became free).  Resources: the leader CPU pays the doorbell, the
-        device flush port pays the local flush, the per-lane wires pay
-        the quorum (``QuorumRound.schedule_on``).  The ordering decides
-        the dependency edges exactly as ``wait()`` decides the scalar
-        combine; with one round in flight at a time every resource clock
-        is ≤ ``after`` when the round starts, so the interval end reduces
-        to ``after + wait()`` — the depth=1 equivalence the tests pin.
-        """
-        if self.round is None:
-            if self.loc_vns:
-                return tl.schedule("flush", busy=self.loc_vns,
-                                   after=after).end
-            return after
-        if self.ordering == REP_LF:
-            t_post = tl.schedule("cpu", busy=self.issue_vns,
-                                 after=after).busy_until
-            flush_end = t_post
-            if self.loc_vns:
-                flush_end = tl.schedule("flush", busy=self.loc_vns,
-                                        after=t_post).end
-            q_end = self.round.schedule_on(tl, t_post)
-            return max(q_end, flush_end)
-        if self.ordering == LF_REP:
-            flush_end = after
-            if self.loc_vns:
-                flush_end = tl.schedule("flush", busy=self.loc_vns,
-                                        after=after).end
-            t_post = tl.schedule("cpu", busy=self.issue_vns,
-                                 after=flush_end).busy_until
-            return self.round.schedule_on(tl, t_post)
-        # PARALLEL: flush and wire race from the doorbell; the measured
-        # DIMM read/write contention penalty rides on top (Fig. 6).
-        t_post = tl.schedule("cpu", busy=self.issue_vns,
-                             after=after).busy_until
-        flush_rel = 0.0
-        if self.loc_vns:
-            flush_rel = tl.schedule("flush", busy=self.loc_vns,
-                                    after=t_post).end - t_post
-        rep_rel = self.round.schedule_on(tl, t_post) - t_post
-        return t_post + max(rep_rel, flush_rel) \
-            + 0.1 * min(self.loc_vns, rep_rel)
+    def wait(self, timeout: Optional[float] = None) -> None:
+        if self.round is not None:
+            self.round.result(timeout)
 
 
 def write_and_force_segs_async(
@@ -269,36 +184,27 @@ def write_and_force_segs_async(
     """
     segs = [(off, n) for off, n in segs if n > 0]
 
-    def _persist_all() -> float:
-        if not local_durable:
-            return 0.0
-        with obs.span(obs.LOG_FLUSH):
-            return sum(dev.persist(off, n) for off, n in segs)
+    def _persist_all() -> None:
+        if local_durable:
+            with obs.span(obs.LOG_FLUSH):
+                _persist_segs(dev, segs, True)
 
     if not segs:
-        return ForceRound(None, 0.0, ordering=ordering)
-    if repl is None:
-        return ForceRound(None, _persist_all(), ordering=ordering)
-    if not repl.live_transports():
-        loc_vns = _persist_all()
-        if repl.write_quorum > (1 if repl.local_is_durable else 0):
+        return ForceRound(None)
+    if repl is None or not repl.live_transports():
+        _persist_all()
+        if repl is not None \
+                and repl.write_quorum > (1 if repl.local_is_durable else 0):
             raise QuorumError("no live backups and local copy alone cannot "
                               f"meet W={repl.write_quorum}")
-        return ForceRound(None, loc_vns, ordering=ordering)
-
-    if ordering == REP_LF:
-        rnd = repl.replicate_batch_async(dev, segs, local_ack_vns=0.0,
-                                         round_lsn=round_lsn)
-        loc_vns = _persist_all()       # overlaps the wire time
-        return ForceRound(rnd, loc_vns, issue_vns=dev.cost.doorbell_ns,
-                          ordering=REP_LF)
-    if ordering in (LF_REP, PARALLEL):
-        loc_vns = _persist_all()
-        rnd = repl.replicate_batch_async(dev, segs, local_ack_vns=loc_vns,
-                                         round_lsn=round_lsn)
-        return ForceRound(rnd, loc_vns, issue_vns=dev.cost.doorbell_ns,
-                          ordering=ordering)
-    raise ValueError(f"unknown ordering {ordering!r}")
+        return ForceRound(None)
+    flush_first = _flush_first(ordering)
+    if flush_first:
+        _persist_all()
+    rnd = repl.replicate_batch_async(dev, segs, round_lsn=round_lsn)
+    if not flush_first:
+        _persist_all()                 # overlaps the wire time
+    return ForceRound(rnd)
 
 
 # ---------------------------------------------------------------------- #
@@ -317,19 +223,15 @@ class SalvageForceRound:
     still advances over a gapless prefix only.  Bundling the fresh range
     into the SAME pipeline round is what makes leader progress past an
     unresolved hole impossible: the fresh bytes cannot become durable
-    unless the salvaged bytes ahead of them do.  ``wait()`` returns the
-    max of the constituent costs (they overlap on the wire) plus the
-    doorbell gap; no local flush is charged for the salvaged ranges —
-    the failed rounds already persisted them at their original issue
-    (the fresh part pays its own flush as usual).
+    unless the salvaged bytes ahead of them do.  No local flush runs for
+    the salvaged ranges — the failed rounds already persisted them at
+    their original issue (the fresh part runs its own flush as usual).
     """
 
     def __init__(self, rounds: List[QuorumRound], reissue_bytes: int,
-                 issue_vns: float = 0.0,
                  fresh: Optional["ForceRound"] = None):
         self.rounds = rounds
         self.reissue_bytes = reissue_bytes
-        self.issue_vns = issue_vns
         self.fresh = fresh
         self._lock = threading.Lock()
 
@@ -365,29 +267,11 @@ class SalvageForceRound:
             states.extend(self.fresh.salvage_states())
         return states
 
-    def wait(self, timeout: Optional[float] = None) -> float:
-        vns = 0.0
+    def wait(self, timeout: Optional[float] = None) -> None:
         for r in self.rounds:
-            vns = max(vns, r.result(timeout))
+            r.result(timeout)
         if self.fresh is not None:
-            vns = max(vns, self.fresh.wait(timeout))
-        return vns + self.issue_vns
-
-    def schedule_on(self, tl: VirtualTimeline, after: float) -> float:
-        """Timeline placement of the bundled salvage round: one doorbell
-        on the leader CPU covers the delta posts, then every constituent
-        round (and the bundled fresh range, which pays its own doorbell
-        and flush) runs from that post in parallel; the bundle completes
-        at the latest constituent end.  Credited acks schedule as pure
-        latency — no wire occupancy — because nothing was re-sent."""
-        t_post = tl.schedule("cpu", busy=self.issue_vns,
-                             after=after).busy_until
-        end = t_post
-        for r in self.rounds:
-            end = max(end, r.schedule_on(tl, t_post))
-        if self.fresh is not None:
-            end = max(end, self.fresh.schedule_on(tl, t_post))
-        return end
+            self.fresh.wait(timeout)
 
 
 def reissue_segs(
@@ -404,10 +288,10 @@ def reissue_segs(
     round's whole range to every backup, post — per backup — only the
     ranges that backup never acked, reusing the wire images the NIC
     DMA-snapshotted at the original post.  Local PMEM is NOT re-flushed
-    (the original issue already persisted the range; ``local_vns``
-    credit inside each salvage carries the local ack), so a salvage
-    round leaves the primary's DeviceStats exactly where a fault-free
-    run would.
+    (the original issue already persisted the range; the salvage's
+    ``local_acked`` credit carries the local ack), so a salvage round
+    leaves the primary's DeviceStats exactly where a fault-free run
+    would.
 
     ``fresh_segs``: the issuing leader's own un-issued range, bundled
     behind the salvage posts as one more constituent round (posted after
@@ -432,9 +316,7 @@ def reissue_segs(
         rnd, nbytes = repl.reissue_round_async(dev, salv)
         rounds.append(rnd)
         posted += nbytes
-    issue_vns = dev.cost.doorbell_ns if posted else 0.0
-    return SalvageForceRound(rounds, posted, issue_vns=issue_vns,
-                             fresh=_fresh())
+    return SalvageForceRound(rounds, posted, fresh=_fresh())
 
 
 # ---------------------------------------------------------------------- #
@@ -466,19 +348,18 @@ class IntegrityRegion:
     def total_size(self) -> int:
         return self.HEADER_SIZE + self.capacity + _CRC.size
 
-    def reliable_write(self, data: bytes, tag: int = 0) -> float:
+    def reliable_write(self, data: bytes, tag: int = 0) -> None:
         if len(data) > self.capacity:
             raise ValueError("payload exceeds region capacity")
         hdr_wo_crc = struct.pack("<II", len(data), tag)
         hdr = hdr_wo_crc + _CRC.pack(crc32(hdr_wo_crc))
-        vns = self.dev.write(self.off, hdr)
-        vns += self.dev.write(self.off + self.HEADER_SIZE, data)
-        vns += self.dev.write(self.off + self.HEADER_SIZE + len(data),
-                              _CRC.pack(crc32(data)))
+        self.dev.write(self.off, hdr)
+        self.dev.write(self.off + self.HEADER_SIZE, data)
+        self.dev.write(self.off + self.HEADER_SIZE + len(data),
+                       _CRC.pack(crc32(data)))
         # ONE replicate+force covers header, payload, and CRC (no barriers).
         n = self.HEADER_SIZE + len(data) + _CRC.size
-        vns += write_and_force(self.dev, self.off, n, self.repl, self.ordering)
-        return vns
+        write_and_force(self.dev, self.off, n, self.repl, self.ordering)
 
     def reliable_read(self) -> Tuple[Optional[bytes], int]:
         """Returns (payload | None-if-corrupt, tag). Header CRC is checked
@@ -543,23 +424,21 @@ class AtomicRegion:
         (v,) = _IDX.unpack(self.dev.read(self.off, 8))
         return int(v & 1)
 
-    def atomic_write(self, data: bytes) -> float:
+    def atomic_write(self, data: bytes) -> None:
         if len(data) != self.size:
             raise ValueError(f"atomic region holds exactly {self.size} bytes")
         cur = self._read_idx()
         nxt = cur ^ 1
         boff = self._buf_off(nxt)
-        vns = self.dev.write(boff, data)
-        vns += self.dev.write(boff + self.size, _CRC.pack(crc32(data)))
-        vns += write_and_force(self.dev, boff, self.size + _CRC.size,
-                               self.repl, self.ordering)
+        self.dev.write(boff, data)
+        self.dev.write(boff + self.size, _CRC.pack(crc32(data)))
+        write_and_force(self.dev, boff, self.size + _CRC.size,
+                        self.repl, self.ordering)
         if self.volatile_index:
             self._vidx = nxt
         else:
-            vns += self.dev.write(self.off, _IDX.pack(nxt))
-            vns += write_and_force(self.dev, self.off, 8, self.repl,
-                                   self.ordering)
-        return vns
+            self.dev.write(self.off, _IDX.pack(nxt))
+            write_and_force(self.dev, self.off, 8, self.repl, self.ordering)
 
     def _read_buf(self, idx: int) -> Optional[bytes]:
         boff = self._buf_off(idx)

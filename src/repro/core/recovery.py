@@ -71,13 +71,10 @@ class CopyAccessor:
 
     @classmethod
     def for_transport(cls, t: Transport) -> "CopyAccessor":
-        def _read(off: int, n: int) -> bytes:
-            data, _ = t.read(off, n)
-            return data
         def _write(off: int, data: bytes) -> None:
             t.write_imm_bytes(data, off)
         return cls(name=t.server.server_id, size=t.server.device.size,
-                   read=_read, write=_write)
+                   read=t.read, write=_write)
 
 
 @dataclass
@@ -174,7 +171,7 @@ def quorum_recover(
     read_quorum = n - write_quorum + 1
     states = [_load_copy(a, cfg) for a in accessors]
     readable = [s for s in states if s.readable]
-    if len(readable) < read_quorum:
+    if not readable or len(readable) < read_quorum:
         bad = {s.acc.name: s.error for s in states if not s.readable}
         raise RecoveryError(
             f"read quorum not met: {len(readable)}/{n} readable "

@@ -19,7 +19,7 @@ from typing import Dict, List, Optional
 from .force_policy import ForcePolicy
 from .ingest import IngestConfig, IngestEngine
 from .log import Log, LogConfig, ring_offset
-from .pmem import CostModel, PMEMDevice
+from .pmem import PMEMDevice
 from .transport import ReplicaServer, ReplicationGroup, Transport
 
 MODES = ("local", "local+remote", "remote_only")
@@ -101,13 +101,13 @@ class ReplicaSet:
                 t.server.unfence(t.primary_id)
         return None
 
-    def trim(self, upto_lsn: int) -> float:
+    def trim(self, upto_lsn: int) -> None:
         """Bulk-truncate ``[head, upto_lsn]`` on every copy (DESIGN.md
         §13): the durable trim watermark advances with one 8-byte-atomic
         store, replicated through the normal lane/quorum machinery so a
         rejoining backup resyncs only the surviving suffix.  Delegates
-        to ``Log.trim``; returns modelled vns."""
-        return self.log.trim(upto_lsn)
+        to ``Log.trim``."""
+        self.log.trim(upto_lsn)
 
     def attach_health(self, cluster=None, scrub=None, heartbeat=None,
                       allow_degraded: bool = False,
@@ -154,7 +154,6 @@ def build_replica_set(
     n_backups: int = 0,
     write_quorum: Optional[int] = None,
     device_mode: str = "fast",
-    cost: Optional[CostModel] = None,
     primary_id: str = "node0",
     open_existing: bool = False,
     pipeline_depth: int = 1,
@@ -193,19 +192,16 @@ def build_replica_set(
                     pipeline_depth=pipeline_depth,
                     adaptive_depth=adaptive_depth, salvage=salvage)
     size = device_size(capacity)
-    cost = cost or CostModel()
     # remote-only staging is DRAM: model as fast device (never persisted)
     primary_dev = PMEMDevice(
         size, mode=device_mode if local_durable else "fast",
-        cost=cost, name=f"{primary_id}/pmem")
+        name=f"{primary_id}/pmem")
     servers = [
-        ReplicaServer(PMEMDevice(size, mode=device_mode, cost=cost,
-                                 name=f"{bid}/pmem"),
+        ReplicaServer(PMEMDevice(size, mode=device_mode, name=f"{bid}/pmem"),
                       server_id=bid)
         for bid in backup_ids
     ]
-    transports = [Transport(s, primary_id=primary_id, cost=cost)
-                  for s in servers]
+    transports = [Transport(s, primary_id=primary_id) for s in servers]
     group = ReplicationGroup(transports, write_quorum,
                              local_is_durable=local_durable) \
         if (servers or mode != "local") else None

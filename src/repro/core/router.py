@@ -24,7 +24,7 @@ concurrently.
                and reports per-shard ``RecoveryReport``s plus the
                aggregate; ``parallel=False`` runs the identical
                protocol serially — the record streams must be
-               byte-identical (pinned by ci_bench).
+               byte-identical (pinned by tests/test_router.py).
   Snapshot   — ``snapshot_cut()`` is a two-phase watermark capture:
                phase one acquires every shard's ``_issue_lock`` in
                fixed shard order (no deadlock: all cutters use the same
@@ -55,7 +55,7 @@ from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 from .force_policy import ForcePolicy
 from .ingest import IngestConfig, IngestEngine, IngestTicket
 from .log import Log, LogConfig
-from .pmem import CostModel, PMEMDevice
+from .pmem import PMEMDevice
 from .recovery import CopyAccessor, RecoveryReport, quorum_recover
 from .replication import ReplicaSet, build_replica_set
 
@@ -118,7 +118,6 @@ class ShardSpec:
     n_backups: int = 0
     write_quorum: Optional[int] = None
     device_mode: str = "fast"
-    cost: Optional[CostModel] = None
     pipeline_depth: int = 1
     adaptive_depth: bool = False
     salvage: bool = True
@@ -262,7 +261,7 @@ class LogRouter:
         rs = build_replica_set(
             mode=spec.mode, capacity=spec.capacity,
             n_backups=spec.n_backups, write_quorum=spec.write_quorum,
-            device_mode=spec.device_mode, cost=spec.cost,
+            device_mode=spec.device_mode,
             primary_id=f"{primary_node}/{spec.shard_id}",
             pipeline_depth=spec.pipeline_depth,
             adaptive_depth=spec.adaptive_depth, salvage=spec.salvage,
@@ -405,13 +404,13 @@ class LogRouter:
         return payload_digest(p for _, _, p in self.cut_records(cut))
 
     # -- lifecycle: per-shard trim (DESIGN.md §13) --------------------------- #
-    def trim_shard(self, shard_id: str, upto_lsn: int) -> float:
+    def trim_shard(self, shard_id: str, upto_lsn: int) -> None:
         """Bulk-truncate one shard's log up to (and including)
         ``upto_lsn`` via its durable trim watermark; sibling shards are
-        untouched.  Returns modelled vns."""
-        return self.shard(shard_id).rs.log.trim(upto_lsn)
+        untouched."""
+        self.shard(shard_id).rs.log.trim(upto_lsn)
 
-    def trim_to_cut(self, cut: SnapshotCut) -> Dict[str, float]:
+    def trim_to_cut(self, cut: SnapshotCut) -> None:
         """Truncate every shard up to its DURABLE watermark in ``cut``.
 
         The caller must have materialized the cut's view first (e.g.
@@ -421,11 +420,9 @@ class LogRouter:
         durable (not issue) watermark keeps the call trivially legal:
         ``Log.trim`` refuses to pass the shard's durable LSN, and
         durable ≤ issue ≤ the cut view's coverage."""
-        out: Dict[str, float] = {}
         for sid, lsn in cut.durable.items():
             log = self.shard(sid).rs.log
-            out[sid] = log.trim(min(lsn, log.durable_lsn))
-        return out
+            log.trim(min(lsn, log.durable_lsn))
 
     # -- shard-parallel recovery -------------------------------------------- #
     def recover(self, parallel: bool = True,
@@ -549,17 +546,6 @@ class LogRouter:
             sh.rs.shutdown()
 
     # -- observability ------------------------------------------------------ #
-    def modelled_makespan_ns(self) -> float:
-        """Modelled completion time of the whole shard fleet: shards are
-        independent devices and wires, so N-way hardware waits on the
-        slowest shard's virtual timeline — a real per-resource timeline
-        max (DESIGN.md §14), not the old ``max(force_vns_total)`` serial
-        sum that ignored each shard's own pipeline overlap."""
-        with self._lock:
-            shards = list(self._shards.values())
-        return max((sh.rs.log.modelled_time_ns() for sh in shards),
-                   default=0.0)
-
     def stats(self) -> dict:
         with self._lock:
             shards = list(self._shards.values())
@@ -579,8 +565,4 @@ class LogRouter:
             totals["appends"] += sh.appends
             totals["bytes_in"] += sh.bytes_in
             totals["records"] += st["log"]["next_lsn"] - 1
-        return dict(shards=per, totals=totals,
-                    n_shards=len(per),
-                    modelled_makespan_ns=max(
-                        (sh.rs.log.modelled_time_ns() for sh in shards),
-                        default=0.0))
+        return dict(shards=per, totals=totals, n_shards=len(per))

@@ -1,7 +1,7 @@
 """RDMA transport model: one-sided verbs against a remote PMEM device.
 
 Models the paper's replication fabric (EDR InfiniBand, RDMA-Write-with-
-Immediate) with the properties that matter for correctness and cost:
+Immediate) with the properties that matter for correctness:
 
   * A ``write_imm`` transfers bytes and carries the length as the immediate
     value; the remote server uses the completion's address + immediate to
@@ -12,14 +12,13 @@ Immediate) with the properties that matter for correctness and cost:
     after the remote-side force.  ``handle_write_imm`` performs both.
   * The NIC reads the source buffer by DMA: lines evicted from LLC by a
     prior local flush must be fetched from PMEM (Fig. 6 effect) —
-    accounted by ``PMEMDevice.dma_read``.
+    counted by ``PMEMDevice.dma_read``.
   * Failures: a transport can be set to drop traffic (network partition /
     backup death ⇒ timeout) and servers can *fence* old primaries by epoch
     (§4.2 Handling Primary Failure).
 
-All hardware waits are virtual ns (see ``CostModel``); data movement is
-real (bytes really land in the backup's device) so recovery tests operate
-on true content.
+Data movement is real (bytes really land in the backup's device), so
+recovery tests operate on true content.
 """
 
 from __future__ import annotations
@@ -32,8 +31,7 @@ from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence, Tuple
 
 from .. import obs
-from .pmem import CostModel, PMEMDevice
-from .timeline import VirtualTimeline
+from .pmem import PMEMDevice
 
 
 class TransportError(Exception):
@@ -84,19 +82,18 @@ class ReplicaServer:
             return primary_id in self._fenced
 
     # -- verbs ------------------------------------------------------------ #
-    def handle_write_imm(self, dst_off: int, data: bytes, primary_id: str) -> float:
+    def handle_write_imm(self, dst_off: int, data: bytes,
+                         primary_id: str) -> None:
         """RDMA-Write lands in the volatile domain; the immediate-value
         completion triggers the persistence primitive; then ack."""
         if self.is_fenced(primary_id):
             raise TransportError(
                 f"{self.server_id}: primary {primary_id} is fenced off")
-        vns = self.device.write(dst_off, data)       # NIC -> caches (volatile)
-        vns += self.device.persist(dst_off, len(data))  # force to PMEM
-        return vns
+        self.device.write(dst_off, data)          # NIC -> caches (volatile)
+        self.device.persist(dst_off, len(data))   # force to PMEM
 
-    def handle_read(self, off: int, n: int) -> Tuple[bytes, float]:
-        data, vns = self.device.dma_read(off, n)
-        return data, vns
+    def handle_read(self, off: int, n: int) -> bytes:
+        return self.device.dma_read(off, n)
 
 
 @dataclass
@@ -122,7 +119,6 @@ class _StagedWrite:
 
     datas: List[Tuple[int, bytes]]
     total: int
-    read_vns: float
     posted_at: float
     round_lsn: Optional[int] = None   # the log round's end LSN, if known
 
@@ -130,13 +126,9 @@ class _StagedWrite:
 class Transport:
     """A reliable-connection QP from the primary to one backup."""
 
-    def __init__(self, server: ReplicaServer, primary_id: str,
-                 cost: Optional[CostModel] = None,
-                 timeout_ns: float = 1e9):
+    def __init__(self, server: ReplicaServer, primary_id: str):
         self.server = server
         self.primary_id = primary_id
-        self.cost = cost or CostModel()
-        self.timeout_ns = timeout_ns
         self.failure = FailureSpec()
         self._ops = 0
         self._closed = False
@@ -161,61 +153,47 @@ class Transport:
     def closed(self) -> bool:
         return self._closed
 
+    def _check_failure(self) -> None:
+        """Injected partition / failure schedule."""
+        if self.failure.drop:
+            raise TransportError(
+                f"timeout (partition to {self.server.server_id})")
+        if 0 <= self.failure.fail_after_ops < self._ops:
+            raise TransportError(
+                f"backup {self.server.server_id} failed (injected)")
+
     def _gate(self) -> None:
         self._ops += 1
         if self._closed:
             raise TransportError("transport closed")
         if self.failure.delay_s > 0:
             time.sleep(self.failure.delay_s)   # injected straggler stall
-        if self.failure.drop:
-            raise TransportError(f"timeout after {self.timeout_ns:.0f} vns "
-                                 f"(partition to {self.server.server_id})")
-        if 0 <= self.failure.fail_after_ops < self._ops:
-            raise TransportError(
-                f"backup {self.server.server_id} failed (injected)")
+        self._check_failure()
 
     # -- verbs ------------------------------------------------------------ #
     def write_imm(self, src_dev: PMEMDevice, src_off: int, dst_off: int,
-                  n: int) -> float:
-        """Replication primitive wire op: one round trip, remote force, ack.
-
-        Returns virtual ns from posting the WQE to receiving the ack.
-        """
+                  n: int) -> None:
+        """Replication primitive wire op: one round trip, remote force, ack."""
         self._gate()
-        data, read_vns = src_dev.dma_read(src_off, n)   # NIC DMA of source
-        wire_vns = self.cost.rdma_rtt_ns + n * self.cost.rdma_byte_ns
-        remote_vns = self.server.handle_write_imm(dst_off, data,
-                                                  self.primary_id)
-        return read_vns + wire_vns + remote_vns
+        data = src_dev.dma_read(src_off, n)          # NIC DMA of source
+        self.server.handle_write_imm(dst_off, data, self.primary_id)
 
-    def write_imm_bytes(self, data: bytes, dst_off: int) -> float:
+    def write_imm_bytes(self, data: bytes, dst_off: int) -> None:
         """Same, but the source is a registered DRAM buffer (remote-only
-        mode): no LLC-miss modelling on the source side."""
+        mode): no NIC read of the source device."""
         self._gate()
-        wire_vns = self.cost.rdma_rtt_ns + len(data) * self.cost.rdma_byte_ns
-        remote_vns = self.server.handle_write_imm(dst_off, data,
-                                                  self.primary_id)
-        return wire_vns + remote_vns
+        self.server.handle_write_imm(dst_off, data, self.primary_id)
 
     def write_imm_batch(self, src_dev: PMEMDevice,
-                        segs: Sequence[Tuple[int, int]]) -> float:
+                        segs: Sequence[Tuple[int, int]]) -> None:
         """Doorbell-batched replication: the scatter list of (off, n)
         ranges is posted as ONE WQE chain — one round trip on the wire —
         while the remote side runs the persistence primitive per range
         (identical remote DeviceStats to per-range write_imm)."""
         self._gate()
-        vns = 0.0
-        total = 0
-        datas = []
-        for off, n in segs:
-            data, read_vns = src_dev.dma_read(off, n)   # NIC DMA per range
-            vns += read_vns
-            total += n
-            datas.append((off, data))
-        vns += self.cost.rdma_rtt_ns + total * self.cost.rdma_byte_ns
+        datas = [(off, src_dev.dma_read(off, n)) for off, n in segs]
         for off, data in datas:
-            vns += self.server.handle_write_imm(off, data, self.primary_id)
-        return vns
+            self.server.handle_write_imm(off, data, self.primary_id)
 
     def post_write_imm_batch(self, src_dev: PMEMDevice,
                              segs: Sequence[Tuple[int, int]]) -> _StagedWrite:
@@ -228,24 +206,16 @@ class Transport:
         self._ops += 1
         if self._closed:
             raise TransportError("transport closed")
-        if self.failure.drop:
-            raise TransportError(f"timeout after {self.timeout_ns:.0f} vns "
-                                 f"(partition to {self.server.server_id})")
-        if 0 <= self.failure.fail_after_ops < self._ops:
-            raise TransportError(
-                f"backup {self.server.server_id} failed (injected)")
+        self._check_failure()
         datas: List[Tuple[int, bytes]] = []
-        read_vns = 0.0
         total = 0
         with obs.span(obs.REPL_POST):
             for off, n in segs:
-                data, vns = src_dev.dma_read(off, n)   # NIC DMA at post time
-                datas.append((off, data))
-                read_vns += vns
+                datas.append((off, src_dev.dma_read(off, n)))  # at post time
                 total += n
-        return _StagedWrite(datas, total, read_vns, time.monotonic())
+        return _StagedWrite(datas, total, time.monotonic())
 
-    def write_imm_staged(self, staged: _StagedWrite) -> float:
+    def write_imm_staged(self, staged: _StagedWrite) -> None:
         """Wire + remote half of a posted write_imm_batch (runs on the
         FIFO lane).  An injected straggler delay counts from the doorbell
         *post*, not from lane dequeue, so in-flight WQEs overlap on the
@@ -257,23 +227,18 @@ class Transport:
                 time.sleep(remaining)
         if self._closed:
             raise TransportError("transport closed")
-        vns = staged.read_vns + self.cost.rdma_rtt_ns \
-            + staged.total * self.cost.rdma_byte_ns
         meta = {} if staged.round_lsn is None \
             else {"round": staged.round_lsn}
         with obs.span(obs.REPL_LANE, **meta):
             for off, data in staged.datas:
-                vns += self.server.handle_write_imm(off, data,
-                                                    self.primary_id)
-        return vns
+                self.server.handle_write_imm(off, data, self.primary_id)
 
-    def read(self, off: int, n: int) -> Tuple[bytes, float]:
+    def read(self, off: int, n: int) -> bytes:
         """One-sided RDMA Read (recovery/repair path)."""
         self._gate()
-        data, remote_vns = self.server.handle_read(off, n)
-        return data, self.cost.rdma_rtt_ns + n * self.cost.rdma_byte_ns + remote_vns
+        return self.server.handle_read(off, n)
 
-    def ping(self) -> float:
+    def ping(self) -> None:
         """Zero-payload heartbeat probe (DESIGN.md §11 failure detector).
 
         Models a dedicated heartbeat QP sharing the physical path with
@@ -285,7 +250,8 @@ class Transport:
         was torn down (the rejoin path reopens it).  Fencing does not
         fail pings either: epoch control is not liveness.  Probes leave
         the data lane's op counter alone so heartbeats never perturb a
-        ``fail_after_ops`` schedule.  Returns the round-trip vns."""
+        ``fail_after_ops`` schedule.  Returns when the probe is acked;
+        raises TransportError when it is not."""
         if self.failure.delay_s > 0:
             time.sleep(self.failure.delay_s)
         if self.failure.drop:
@@ -294,7 +260,6 @@ class Transport:
         if 0 <= self.failure.fail_after_ops < self._ops:
             raise TransportError(
                 f"backup {self.server.server_id} failed (injected)")
-        return self.cost.rdma_rtt_ns
 
 
 @dataclass
@@ -313,8 +278,8 @@ class RoundSalvage:
 
     segs: List[Tuple[int, int]]                       # ranges the round covered
     total: int                                        # sum of range bytes
-    local_vns: Optional[float]                        # local ack credit
-    acked: List[Tuple["Transport", float]]            # lanes that acked
+    local_acked: bool                                 # local ack credit
+    acked: List["Transport"]                          # lanes that acked
     pending: List[Tuple["Transport", Optional[_StagedWrite]]]  # never acked
 
 
@@ -323,7 +288,7 @@ class QuorumRound:
 
     Returned by the ``*_async`` issue paths once the doorbell has been
     posted on every live lane.  ``result()`` blocks until the round
-    settles: quorum met (returns the W-th smallest ack vns) or quorum
+    settles: quorum met (returns at the W-th ack) or quorum
     arithmetically unreachable (raises QuorumError; a non-transport lane
     error is re-raised instead and un-stashed from the group's deferred
     list).  ``add_done_callback`` fires exactly once when the round
@@ -332,7 +297,7 @@ class QuorumRound:
     dedicated retirement thread.
 
     Acks carry identity: the round records *which* lane acked (and which
-    never did) alongside the vns figures, so a failed round can be
+    never did), so a failed round can be
     ``salvage()``d — re-issued as only its unacked (backup × range)
     deltas instead of from scratch (DESIGN.md §9).
     """
@@ -342,49 +307,38 @@ class QuorumRound:
         self._group = group
         self._w = write_quorum
         self._cv = threading.Condition()
-        self._acks: List[float] = []
+        self._n_acks = 0
         self._outstanding = 0
         self._sealed = False
         self._fatal: Optional[BaseException] = None
         self._callbacks: List[Callable[[], None]] = []
         # per-lane ack identity (salvage bookkeeping)
         self.segs: List[Tuple[int, int]] = list(segs or [])
-        self._local_vns: Optional[float] = None
+        self._local_acked = False
         self._fut_lane: dict = {}                 # Future -> Transport
-        self._lane_acked: List[Tuple[Transport, float]] = []
+        self._lane_acked: List[Transport] = []
         self._lane_pending: dict = {}             # Transport -> _StagedWrite|None
-        # timeline bookkeeping (DESIGN.md §14): the acks that counted
-        # toward _acks, in arrival order, with lane identity (None =
-        # local ack), and each posted lane's wire *occupancy* — the vns
-        # the lane is busy (NIC source read + bytes on the wire) before
-        # the RTT/remote-persist latency tail that does not occupy it.
-        self._sched: List[Tuple[Optional[Transport], float]] = []
-        self._lane_occ: dict = {}                 # Transport -> occupancy vns
 
     # -- issue-side wiring (group only) ---------------------------------- #
-    def _ack_local(self, vns: float) -> None:
-        self._local_vns = vns
-        self._acks.append(vns)
-        self._sched.append((None, vns))
+    def _ack_local(self) -> None:
+        self._local_acked = True
+        self._n_acks += 1
 
-    def _credit(self, t: "Transport", vns: float) -> None:
+    def _credit(self, t: "Transport") -> None:
         """Bank a prior ack (a lane that acked the original round and is
         still live) without any wire traffic — with identity, so a
         failed re-issue can itself be salvaged without losing it."""
         with self._cv:
-            self._acks.append(vns)
-            self._lane_acked.append((t, vns))
-            # no _lane_occ entry: a banked credit sends nothing on the
-            # wire this round, so it is pure latency on the timeline
-            self._sched.append((t, vns))
+            self._n_acks += 1
+            self._lane_acked.append(t)
 
-    def _note_acked(self, t: "Transport", vns: float) -> None:
+    def _note_acked(self, t: "Transport") -> None:
         """A lane that acked the original round but is not live now: its
         copy exists but cannot count toward this round's quorum.  Keep
         the identity so the credit revives if the backup rejoins before
         a later salvage."""
         with self._cv:
-            self._lane_acked.append((t, vns))
+            self._lane_acked.append(t)
 
     def _track(self, fut: Future, t: Optional["Transport"] = None,
                staged: Optional[_StagedWrite] = None) -> None:
@@ -404,14 +358,9 @@ class QuorumRound:
         with self._cv:
             self._lane_pending.setdefault(t, staged)
 
-    def _set_occ(self, t: "Transport", occ: float) -> None:
-        """Record a posted lane's wire occupancy (set at post time)."""
-        with self._cv:
-            self._lane_occ[t] = occ
-
     def _settled_locked(self) -> bool:
-        return (len(self._acks) >= self._w
-                or (self._sealed and len(self._acks) + self._outstanding
+        return (self._n_acks >= self._w
+                or (self._sealed and self._n_acks + self._outstanding
                     < self._w))
 
     def _fire_if_settled(self) -> None:
@@ -430,12 +379,10 @@ class QuorumRound:
                 TransportError("lane op cancelled")
             t = self._fut_lane.pop(fut, None)
             if exc is None:
-                vns = fut.result()
-                self._acks.append(vns)
-                self._sched.append((t, vns))
+                self._n_acks += 1
                 if t is not None:
                     self._lane_pending.pop(t, None)
-                    self._lane_acked.append((t, vns))
+                    self._lane_acked.append(t)
             elif not isinstance(exc, TransportError) and self._fatal is None:
                 self._fatal = exc
         self._fire_if_settled()
@@ -462,46 +409,9 @@ class QuorumRound:
             return RoundSalvage(
                 segs=list(self.segs),
                 total=sum(n for _, n in self.segs),
-                local_vns=self._local_vns,
+                local_acked=self._local_acked,
                 acked=list(self._lane_acked),
                 pending=list(self._lane_pending.items()))
-
-    def schedule_on(self, tl: VirtualTimeline, t_post: float) -> float:
-        """Place this round's acks on the virtual timeline and return the
-        modelled vtime at which the write quorum filled (DESIGN.md §14).
-
-        ``t_post`` is the vtime the doorbells were posted.  Each counted
-        ack becomes an interval: a lane ack occupies its wire resource
-        for the post-time occupancy (NIC source read + bytes on the
-        wire) and carries the rest of its vns (RTT + remote persist) as
-        non-occupying latency, so back-to-back rounds overlap on the
-        lane exactly as in-flight WQEs do on an RC QP.  Local acks and
-        banked salvage credits sent nothing this round and are pure
-        latency.  The quorum fills at the W-th smallest end.
-
-        Lanes still in flight when the round retires are not scheduled
-        (their clocks do not advance) — the same stragglers the legacy
-        scalar model ignored.
-        """
-        with self._cv:
-            sched = list(self._sched)
-            occ = dict(self._lane_occ)
-            w = self._w
-        ends: List[float] = []
-        for t, vns in sched:
-            lane_occ = occ.get(t) if t is not None else None
-            if lane_occ is None:
-                ends.append(t_post + vns)
-            else:
-                iv = tl.schedule(f"wire:{t.server.server_id}",
-                                 busy=lane_occ,
-                                 latency=max(vns - lane_occ, 0.0),
-                                 after=t_post)
-                ends.append(iv.end)
-        if not ends:
-            return t_post
-        ends.sort()
-        return ends[w - 1] if len(ends) >= w else ends[-1]
 
     def add_done_callback(self, fn: Callable[[], None]) -> None:
         with self._cv:
@@ -510,17 +420,18 @@ class QuorumRound:
                 return
         fn()
 
-    def result(self, timeout: Optional[float] = None) -> float:
-        """W-th smallest ack vns; QuorumError if the quorum cannot fill;
-        TimeoutError if the round has not settled within ``timeout``."""
+    def result(self, timeout: Optional[float] = None) -> None:
+        """Return once W acks are in; QuorumError if the quorum cannot
+        fill; TimeoutError if the round has not settled within
+        ``timeout``."""
         with self._cv:
             if not self._cv.wait_for(self._settled_locked, timeout):
                 raise TimeoutError("quorum round still in flight")
-            if len(self._acks) >= self._w:
-                return sorted(self._acks)[self._w - 1]
+            if self._n_acks >= self._w:
+                return
             exc: BaseException = self._fatal if self._fatal is not None \
                 else QuorumError(f"write quorum {self._w} not met "
-                                 f"({len(self._acks)} acks)")
+                                 f"({self._n_acks} acks)")
         if not isinstance(exc, QuorumError):
             # un-stash the harvest's copy so it doesn't re-raise on a
             # later unrelated call (same contract as the sync round)
@@ -586,7 +497,7 @@ class ReplicationGroup:
 
     # -- straggler bookkeeping -------------------------------------------- #
     def _submit(self, t: Transport,
-                op: Callable[[Transport], float]) -> Future:
+                op: Callable[[Transport], None]) -> Future:
         fut = self._lanes[t].submit(op, t)
         with self._pending_cv:
             self._pending.add(fut)
@@ -640,32 +551,29 @@ class ReplicationGroup:
         return drained
 
     # -- quorum rounds ----------------------------------------------------- #
-    def _quorum_round(self, op: Callable[[Transport], float],
-                      local_ack_vns: Optional[float]) -> float:
-        """Issue ``op`` on every live lane; return at the W-th ack.
+    def _quorum_round(self, op: Callable[[Transport], None]) -> None:
+        """Issue ``op`` on every live lane; return at the W-th ack (the
+        local durable copy, when there is one, counts as one ack).
 
-        The returned figure is the W-th smallest ack vns among the acks
-        collected when the quorum filled.  Stragglers keep running on
-        their lanes and are harvested in the background (eviction on late
-        TransportError happens before that lane's next op).  Raises
-        QuorumError as soon as the quorum is arithmetically unreachable.
+        Stragglers keep running on their lanes and are harvested in the
+        background (eviction on late TransportError happens before that
+        lane's next op).  Raises QuorumError as soon as the quorum is
+        arithmetically unreachable.
         """
         self._raise_deferred()
-        acks: List[float] = []
-        if self.local_is_durable and local_ack_vns is not None:
-            acks.append(local_ack_vns)
+        acks = 1 if self.local_is_durable else 0
         pending = {self._submit(t, op) for t in self.live_transports()}
         w = self.write_quorum
-        while len(acks) < w:
-            if len(acks) + len(pending) < w:
+        while acks < w:
+            if acks + len(pending) < w:
                 raise QuorumError(
                     f"write quorum {w} not met "
-                    f"({len(acks)}/{self.n_replicas} acks)")
+                    f"({acks}/{self.n_replicas} acks)")
             done, pending = wait(pending, return_when=FIRST_COMPLETED)
             for fut in done:
                 exc = fut.exception()
                 if exc is None:
-                    acks.append(fut.result())
+                    acks += 1
                 elif not isinstance(exc, TransportError):
                     # programming error: never swallow — raise here, and
                     # un-stash the harvest's copy so it doesn't re-raise
@@ -678,36 +586,27 @@ class ReplicationGroup:
                         except ValueError:
                             pass
                     raise exc
-        acks.sort()
-        return acks[w - 1]
 
     def replicate(self, src_dev: PMEMDevice, src_off: int, dst_off: int,
-                  n: int, local_ack_vns: float = 0.0) -> float:
-        """Replicate+force [src_off, src_off+n) to every backup; wait for a
-        write quorum of acks.  ``local_ack_vns`` is the completion time of
-        the local durable copy (0 if none / already persisted).
-
-        Returns the vns at which the W-th ack arrived.  Raises QuorumError
-        if the quorum cannot be met; failed backups are evicted (at the
-        latest, before the next replicate reuses their lane).
+                  n: int) -> None:
+        """Replicate+force [src_off, src_off+n) to every backup; return
+        once a write quorum of acks is in.  Raises QuorumError if the
+        quorum cannot be met; failed backups are evicted (at the latest,
+        before the next replicate reuses their lane).
         """
-        return self._quorum_round(
-            lambda t: t.write_imm(src_dev, src_off, dst_off, n),
-            local_ack_vns)
+        self._quorum_round(
+            lambda t: t.write_imm(src_dev, src_off, dst_off, n))
 
     def replicate_batch(self, src_dev: PMEMDevice,
-                        segs: Sequence[Tuple[int, int]],
-                        local_ack_vns: float = 0.0) -> float:
+                        segs: Sequence[Tuple[int, int]]) -> None:
         """Replicate+force a scatter list of (off, n) ranges in ONE quorum
         round per backup (doorbell-batched write_imm): one wire round trip
         and one W-th-ack wait cover every range."""
         segs = list(segs)
-        return self._quorum_round(
-            lambda t: t.write_imm_batch(src_dev, segs), local_ack_vns)
+        self._quorum_round(lambda t: t.write_imm_batch(src_dev, segs))
 
     def replicate_batch_async(self, src_dev: PMEMDevice,
                               segs: Sequence[Tuple[int, int]],
-                              local_ack_vns: Optional[float] = 0.0,
                               round_lsn: Optional[int] = None
                               ) -> QuorumRound:
         """Post one doorbell-batched replication round on every live lane
@@ -725,8 +624,8 @@ class ReplicationGroup:
         segs = list(segs)
         self._raise_deferred()
         rnd = QuorumRound(self, self.write_quorum, segs=segs)
-        if self.local_is_durable and local_ack_vns is not None:
-            rnd._ack_local(local_ack_vns)
+        if self.local_is_durable:
+            rnd._ack_local()
         for t in self.live_transports():
             try:
                 staged = t.post_write_imm_batch(src_dev, segs)
@@ -735,8 +634,6 @@ class ReplicationGroup:
                 rnd._note_unposted(t)
                 continue
             staged.round_lsn = round_lsn
-            rnd._set_occ(t, staged.read_vns
-                         + staged.total * t.cost.rdma_byte_ns)
             fut = self._submit(t, lambda tt, s=staged: tt.write_imm_staged(s))
             rnd._track(fut, t, staged)
         rnd._seal()
@@ -756,19 +653,19 @@ class ReplicationGroup:
         (``reissue_segs`` does).  Returns (round, bytes actually re-sent).
         """
         rnd = QuorumRound(self, self.write_quorum, segs=salv.segs)
-        if self.local_is_durable and salv.local_vns is not None:
-            rnd._ack_local(salv.local_vns)
+        if self.local_is_durable and salv.local_acked:
+            rnd._ack_local()
         live = set(self.live_transports())
-        for t, vns in salv.acked:
+        for t in salv.acked:
             if t in live:
-                rnd._credit(t, vns)
+                rnd._credit(t)
             else:
-                rnd._note_acked(t, vns)
+                rnd._note_acked(t)
         # a lane the original round never reached (it was already evicted
         # at issue time) but which is live again now: it must receive the
         # ranges too, or a W that needs it can never fill — no snapshot
         # exists for it, so it takes the re-snapshot path below
-        seen = {t for t, _ in salv.acked} | {t for t, _ in salv.pending}
+        seen = set(salv.acked) | {t for t, _ in salv.pending}
         pending = list(salv.pending) + [(t, None) for t in live
                                         if t not in seen]
         posted_bytes = 0
@@ -785,24 +682,21 @@ class ReplicationGroup:
                     continue
             else:
                 # refresh the post anchor (straggler delays count from the
-                # doorbell); the DMA snapshot and its read cost were paid
-                # at the original post — charge nothing again
-                staged = _StagedWrite(staged.datas, staged.total, 0.0,
+                # doorbell); the DMA snapshot was taken at the original
+                # post — the device is not read again
+                staged = _StagedWrite(staged.datas, staged.total,
                                       time.monotonic())
-            rnd._set_occ(t, staged.read_vns
-                         + staged.total * t.cost.rdma_byte_ns)
             fut = self._submit(t, lambda tt, s=staged: tt.write_imm_staged(s))
             rnd._track(fut, t, staged)
             posted_bytes += staged.total
         rnd._seal()
         return rnd, posted_bytes
 
-    def broadcast_bytes(self, data: bytes, dst_off: int) -> float:
+    def broadcast_bytes(self, data: bytes, dst_off: int) -> None:
         """Replicate a small DRAM buffer (superline updates, epoch bumps).
         Fans out over the lanes in parallel and completes at the W-th ack,
         like replicate."""
-        return self._quorum_round(
-            lambda t: t.write_imm_bytes(data, dst_off), 0.0)
+        self._quorum_round(lambda t: t.write_imm_bytes(data, dst_off))
 
     def shutdown(self) -> None:
         for lane in self._lanes.values():

@@ -504,9 +504,12 @@ def test_soak_trim_racing_backup_resync():
     eng.drain(timeout=30)
     rs.log.drain(timeout=10.0)
     rs.group.drain(timeout=10.0)
-    # one more settled trim: the rejoined lane must replicate it too
+    # one more settled trim: the rejoined lane must replicate it too.
+    # The trim returns at its write quorum (W=2 of 3), so the third
+    # copy's lane may still hold it: settle the lanes before comparing
     if rs.log.durable_lsn - 4 > rs.log.trim_lsn:
         rs.trim(rs.log.durable_lsn - 4)
+    rs.group.drain(timeout=10.0)
     for t in tickets:
         assert t.wait(timeout=30) <= rs.log.durable_lsn
     _trim_slots_agree(rs)

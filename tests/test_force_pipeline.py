@@ -9,6 +9,7 @@ import pytest
 
 from repro.core import (ClusterManager, FreqPolicy, Log, LogConfig, LogError,
                         Node, PMEMDevice, QuorumError, build_replica_set)
+from repro.core.log import _PipeRound
 from repro.core.replication import device_size
 
 pytestmark = pytest.mark.slow   # spins up replica servers per test
@@ -400,3 +401,123 @@ def test_effective_bound_per_round_span_accounting_at_depth1():
     assert pol.effective_vulnerability_bound(log) == 4
     rs.group.drain()
     rs.shutdown()
+
+
+# --------------------------------------------------------------------- #
+# durable watermark and ack times across salvage; ack-time attribution
+# --------------------------------------------------------------------- #
+def test_salvage_round_keeps_watermark_and_ack_times_monotone():
+    """A mid-pipeline backup death fails in-flight rounds; the salvage
+    re-issue retires them, the durable watermark never moves back, and
+    every record's ack time is its covering round's retirement, in LSN
+    order."""
+    rs = build_replica_set(mode="local+remote", capacity=CAP,
+                           n_backups=2, write_quorum=3, pipeline_depth=4)
+    pol = FreqPolicy(4, wait=False)
+    for _ in range(8):
+        rs.log.append(b"v" * 64)
+    rs.log.drain()
+    seen = [rs.log.durable_lsn]
+    rs.transports[0].inject(delay_s=0.03)
+    rs.transports[1].inject(delay_s=0.002)
+    for i in range(32):
+        if i == 16:
+            rs.kill_backup_midwire("node1", settle_s=0.016)
+            rs.recover_backup("node1")
+        rid, ptr = rs.log.reserve(64)
+        ptr[:] = b"v" * 64
+        rs.log.complete(rid)
+        pol.on_complete(rs.log, rid)
+        seen.append(rs.log.durable_lsn)
+    pol.drain(rs.log)
+    st = rs.log.stats()
+    times = rs.log.durable_ack_times(list(range(1, 41)))
+    rs.group.drain()
+    rs.shutdown()
+    assert st["salvage_rounds"] >= 1             # the scenario really fired
+    assert seen == sorted(seen)                  # watermark monotone
+    assert st["durable_lsn"] == 40
+    assert None not in times
+    assert times == sorted(times)                # retirement is head-first
+
+
+def test_ack_time_is_the_covering_round_retirement():
+    """Single-threaded sync stream: each record rides its own round, so
+    its ack time is that round's retirement, inside its own append; the
+    members of one batch ride one round and share its stamp."""
+    rs = build_replica_set(mode="local+remote", capacity=CAP,
+                           n_backups=2, write_quorum=3, pipeline_depth=1)
+    rids, stamps = [], []
+    for i in range(12):
+        t0 = time.monotonic()
+        rid = rs.log.append(bytes([i]) * 80)
+        t1 = time.monotonic()
+        at = rs.log.durable_ack_time(rid)
+        assert at is not None and t0 <= at <= t1
+        rids.append(rid)
+        stamps.append(at)
+    assert stamps == sorted(stamps) and len(set(stamps)) == len(stamps)
+    assert rs.log.durable_ack_times(rids + rids) == stamps + stamps
+    lsns = rs.log.append_batch([b"b" * 80] * 4)
+    batch = rs.log.durable_ack_times(lsns)
+    assert len(set(batch)) == 1 and batch[0] > stamps[-1]
+    assert rs.log.stats()["rounds_retired"] == 13
+    rs.group.drain()
+    rs.shutdown()
+
+
+def test_ack_time_history_boundaries():
+    rs = build_replica_set(mode="local+remote", capacity=CAP,
+                           n_backups=2, write_quorum=3, pipeline_depth=1)
+    rid = rs.log.append(b"a" * 64)
+    assert rs.log.durable_ack_time(rid + 1) is None     # not durable yet
+    at = rs.log.durable_ack_time(rid)
+    assert at is not None
+    assert rs.log.durable_ack_times([rid, rid + 1]) == [at, None]
+    rs.group.drain()
+    rs.shutdown()
+
+
+# --------------------------------------------------------------------- #
+# pump exception discipline
+# --------------------------------------------------------------------- #
+class _KIHandle:
+    """Settled handle whose first wait raises KeyboardInterrupt — the
+    settling thread being interrupted, not the round failing."""
+
+    def __init__(self):
+        self._raised = False
+
+    def done(self):
+        return True
+
+    def wait(self, timeout=None):
+        if not self._raised:
+            self._raised = True
+            raise KeyboardInterrupt()
+
+
+def test_pump_lets_keyboard_interrupt_propagate_without_failing_round():
+    """An operator Ctrl-C on the settling thread must propagate from
+    _pipe_pump, not be converted into a permanently failed round, and
+    leave the round retire-able."""
+    dev = PMEMDevice(device_size(CAP))
+    log = Log(dev, LogConfig(capacity=CAP, pipeline_depth=2))
+    rid, ptr = log.reserve(64)
+    ptr[:] = b"k" * 64
+    log.complete(rid)
+    entry = _PipeRound(rid, 0, 128, gen=log._salvage_gen,
+                       issued_at=time.monotonic())
+    entry.handle = _KIHandle()
+    with log._commit_cv:
+        log._inflight.append(entry)
+    with pytest.raises(KeyboardInterrupt):
+        log._pipe_pump()
+    # the interrupt did NOT poison the pipeline
+    assert entry.error is None
+    assert log._inflight and log._inflight[0] is entry
+    # the next pump retires the round cleanly
+    log._pipe_pump()
+    assert not log._inflight
+    assert log.durable_lsn == rid
+    assert log.stats()["rounds_retired"] == 1

@@ -53,7 +53,6 @@ def test_scrub_clean_log_finds_nothing():
     rep = sc.scrub_once()
     assert rep.complete and rep.corrupt == 0 and rep.repair_bytes == 0
     assert rep.scanned_records == 12 * 3     # every record on every copy
-    assert rep.vns > 0                       # scan time is modelled
     rs.shutdown()
 
 
@@ -182,67 +181,6 @@ def test_scrub_background_thread_mode():
     rs.shutdown()
 
 
-def test_scrub_splits_scan_and_repair_vns():
-    """PR-10 satellite: the scrubber used to charge one undifferentiated
-    ``vns`` total, so a repair-heavy pass and a clean scan were
-    indistinguishable and the budget throttled repairs.  Scan and repair
-    charges must now be split, with ``vns`` their sum for compat."""
-    rs = _rs()
-    lsns = _fill(rs)
-    sc = Scrubber.from_replica_set(rs)
-    rep = sc.scrub_once()
-    assert rep.scan_vns > 0
-    assert rep.repair_vns == 0                   # clean pass: no repairs
-    assert rep.vns == rep.scan_vns + rep.repair_vns
-    rng = np.random.default_rng(7)
-    assert _corrupt_payload(rs.servers[0].device, rs.log, lsns[3], rng)
-    rep2 = sc.scrub_once()
-    assert rep2.repaired == 1
-    assert rep2.repair_vns > 0
-    assert rep2.vns == rep2.scan_vns + rep2.repair_vns
-    st = sc.stats()
-    assert st["scan_vns"] == rep.scan_vns + rep2.scan_vns
-    assert st["repair_vns"] == rep2.repair_vns
-    assert st["scrub_vns"] == st["scan_vns"] + st["repair_vns"]
-    rs.shutdown()
-
-
-def test_scrub_vns_budget_bounds_scan_not_repair():
-    """The modelled-time budget bounds the SCAN slice per pass; repair
-    of whatever that slice uncovered is corrective work that must run
-    regardless — a tightly budgeted scrubber still converges."""
-    rs = _rs()
-    lsns = _fill(rs, n=16)
-    rng = np.random.default_rng(11)
-    assert _corrupt_payload(rs.servers[0].device, rs.log, lsns[5], rng)
-    # ~2-3 record x 3-copy scans per pass
-    budget = 150.0
-    sc = Scrubber.from_replica_set(
-        rs, cfg=ScrubConfig(max_vns_per_pass=budget))
-    reports = sc.scrub_to_completion(max_passes=64)
-    assert len(reports) > 2                      # budget really sliced it
-    assert sc.stats()["repaired"] == 1
-    # each pass overshoots the scan budget by at most one record's scan
-    # charge (the check runs after charging), never by repair traffic
-    per_rec = max(r.scan_vns / max(r.scanned_records, 1) for r in reports)
-    assert all(r.scan_vns <= budget + 3 * per_rec for r in reports)
-    assert any(r.repair_vns > 0 for r in reports)
-    rs.shutdown()
-
-
-def test_scrub_charges_log_timeline():
-    """Background scrub work rides the log's virtual timeline on its own
-    resource, so modelled time covers it (DESIGN.md §14)."""
-    rs = _rs()
-    _fill(rs)
-    sc = Scrubber.from_replica_set(rs)
-    rep = sc.scrub_once()
-    clocks = rs.log.timeline.clocks()
-    assert clocks.get("scrub", 0.0) == pytest.approx(rep.scan_vns)
-    assert rs.log.modelled_time_ns() >= rs.log.durable_vtime
-    rs.shutdown()
-
-
 # --------------------------------------------------------------------- #
 # online backup resync
 # --------------------------------------------------------------------- #
@@ -328,13 +266,13 @@ def test_resync_under_hot_ingest_keeps_log_live():
 def test_ping_fails_on_partition_not_on_eviction():
     rs = _rs()
     t = rs.transports[0]
-    assert t.ping() > 0
+    t.ping()                  # acked: returns without raising
     t.inject(drop=True)
     with pytest.raises(Exception):
         t.ping()
     t.inject()
     t.close()                 # evicted data lane: heartbeat QP still up
-    assert t.ping() > 0
+    t.ping()
     rs.shutdown()
 
 

@@ -11,7 +11,7 @@ IngestQueueFull, shed raises IngestShedError after its deadline.
 
 Accounting: per-record latency is the submit→durable-ack interval
 stamped from the covering round's retirement (Log.durable_ack_time),
-the append_timed/append_batch_timed per_record axis reports honest
+append/append_batch followed by durable_ack_time(s) report honest
 per-record ack times, and the ack-rate (BDP) grow signal follows a
 pinned trajectory on a deterministic schedule.
 """
@@ -244,19 +244,21 @@ def test_ticket_wait_timeout_raises_ingest_error():
 
 
 # --------------------------------------------------------------------- #
-# per-record latency attribution (append_timed / append_batch_timed)
+# per-record latency attribution (durable_ack_time / durable_ack_times)
 # --------------------------------------------------------------------- #
 def test_append_timed_per_record_reports_ack_time():
     _, log = _local_log()
-    rid, vns, ack = log.append_timed(b"a" * 32, per_record=True)
-    assert rid == 1 and vns > 0
-    assert ack is not None and ack <= time.monotonic()
+    t0 = time.monotonic()
+    rid = log.append(b"a" * 32)
+    ack = log.durable_ack_time(rid)
+    assert rid == 1
+    assert ack is not None and t0 <= ack <= time.monotonic()
 
 
 def test_append_batch_timed_per_record_acks_every_member():
     _, log = _local_log()
-    lsns, vns, acks = log.append_batch_timed([b"b" * 32] * 10,
-                                             per_record=True)
+    lsns = log.append_batch([b"b" * 32] * 10)
+    acks = log.durable_ack_times(lsns)
     assert len(acks) == len(lsns) == 10
     assert all(a is not None for a in acks)
     assert acks == sorted(acks)
@@ -266,7 +268,7 @@ def test_append_batch_timed_per_record_acks_every_member():
 
 def test_unforced_records_have_no_ack_time():
     _, log = _local_log()
-    lsns, _vns = log.append_batch_timed([b"u" * 16] * 8, freq=64)
+    lsns = log.append_batch([b"u" * 16] * 8, freq=64)
     assert log.durable_ack_time(lsns[-1]) is None   # never forced
     log.force(lsns[-1])
     assert log.durable_ack_time(lsns[-1]) is not None

@@ -9,9 +9,9 @@ hypothesis = pytest.importorskip(
     "hypothesis", reason="property tests need hypothesis (pip extra: test)")
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (AtomicRegion, IntegrityRegion, LF_REP, Log,
-                        LogConfig, ORDERINGS, PARALLEL, PMEMDevice, REP_LF,
-                        make_policy, persist, write_and_force)
+from repro.core import (AtomicRegion, IntegrityRegion, Log, LogConfig,
+                        ORDERINGS, PMEMDevice, REP_LF, make_policy, persist,
+                        write_and_force)
 from repro.core.replication import build_replica_set
 from repro.core.transport import QuorumError
 
@@ -48,27 +48,29 @@ def test_write_and_force_orderings_all_replicate(ordering):
     dev = rs.primary_dev
     off = rs.log.ring_off
     dev.write(off, b"payload!" * 16)
-    vns = write_and_force(dev, off, 128, rs.group, ordering)
-    assert vns > 0
+    write_and_force(dev, off, 128, rs.group, ordering)
     for s in rs.servers:
-        assert s.device.read(off, 128) == dev.read(off, 128)
+        assert s.device.read(off, 128) == b"payload!" * 16
+        assert s.device.dirty_units() == 0          # remote-persisted
+    assert dev.stats.lines_flushed >= 2             # local copy flushed
     rs.shutdown()
 
 
-def test_rep_lf_is_fastest_ordering():
-    """Fig. 6a: replicate-first keeps source lines in LLC for the NIC."""
-    times = {}
-    for ordering in ORDERINGS:
-        rs = build_replica_set(mode="local+remote", capacity=1 << 16,
-                               n_backups=1, write_quorum=2)
-        dev, off = rs.primary_dev, rs.log.ring_off
-        total = 0.0
-        for _ in range(50):
-            dev.write(off, b"z" * 1024)
-            total += write_and_force(dev, off, 1024, rs.group, ordering)
-        times[ordering] = total
-        rs.shutdown()
-    assert times[REP_LF] < times[LF_REP] <= times[PARALLEL]
+@pytest.mark.parametrize("ordering", ORDERINGS)
+def test_rep_lf_keeps_source_lines_in_llc(ordering):
+    """Fig. 6a: replicate-first lets the NIC read the source lines from
+    LLC; flushing first evicts them, so every NIC read misses."""
+    rs = build_replica_set(mode="local+remote", capacity=1 << 16,
+                           n_backups=1, write_quorum=2)
+    dev, off = rs.primary_dev, rs.log.ring_off
+    hits, misses = dev.stats.llc_hits, dev.stats.llc_misses
+    for _ in range(10):
+        dev.write(off, b"z" * 1024)
+        write_and_force(dev, off, 1024, rs.group, ordering)
+    hits, misses = dev.stats.llc_hits - hits, dev.stats.llc_misses - misses
+    rs.shutdown()
+    assert hits + misses == 10 * 16                 # 1 KiB = 16 lines
+    assert (hits, misses) == ((160, 0) if ordering == REP_LF else (0, 160))
 
 
 # -------------------------- integrity ---------------------------------- #

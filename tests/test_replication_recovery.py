@@ -155,6 +155,15 @@ def test_read_quorum_not_met():
         quorum_recover(accs, rs.cfg, write_quorum=2)
 
 
+def test_no_readable_copy_raises_recovery_error():
+    # one accessor against W=2 asks for a read quorum of 0: an empty
+    # (never written) copy must still fail as a RecoveryError
+    blank = PMEMDevice(device_size(CAP))
+    with pytest.raises(RecoveryError, match="read quorum not met"):
+        quorum_recover([CopyAccessor.for_device("node1", blank)],
+                       LogConfig(capacity=CAP), write_quorum=2)
+
+
 def test_diverging_histories_epoch_resolution():
     """The paper's §4.2 A/B/C example, verbatim."""
     size = device_size(CAP)

@@ -38,9 +38,8 @@ except ImportError:          # deterministic sweeps still run without it
                 reason="property tests need hypothesis (pip extra: test)")(fn)
         return deco
 
-from repro.core import (ClusterManager, FreqPolicy, LF_REP, Log, LogConfig,
-                        Node, ORDERINGS, PARALLEL, QuorumError, REP_LF,
-                        build_replica_set, write_and_force_segs_async)
+from repro.core import (ClusterManager, FreqPolicy, Log, LogConfig, Node,
+                        QuorumError, build_replica_set)
 
 pytestmark = pytest.mark.slow   # spins up replica servers per test
 
@@ -512,51 +511,6 @@ def test_effective_vulnerability_bound_tracks_live_depth():
         pol.vulnerability_bound(log)
     rs.group.drain()
     rs.shutdown()
-
-
-# --------------------------------------------------------------------- #
-# satellite: the async orderings' modelled costs (regression pin)
-# --------------------------------------------------------------------- #
-def _ordering_cost(ordering):
-    rs = build_replica_set(mode="local+remote", capacity=CAP, n_backups=1,
-                           write_quorum=2)
-    dev = rs.primary_dev
-    off = rs.log.ring_off
-    dev.write(off, b"c" * 1024)
-    fr = write_and_force_segs_async(dev, [(off, 1024)], rs.group, ordering)
-    rep_vns = fr.round.result(timeout=5.0)
-    total = fr.wait(timeout=5.0)
-    parts = (fr.loc_vns, rep_vns, dev.cost.doorbell_ns)
-    rs.group.drain()
-    rs.shutdown()
-    return total, parts
-
-
-def test_async_ordering_costs_are_overlapped_not_serial():
-    """Pin of the PR-5 cost fix: every async ordering pays the doorbell
-    issue gap, and whatever genuinely overlaps is charged max() not sum —
-    REP_LF and PARALLEL overlap wire and flush; LF_REP alone is serial
-    because its ordering requires the flush to retire first."""
-    for ordering in ORDERINGS:
-        total, (loc, rep, bell) = _ordering_cost(ordering)
-        if ordering == REP_LF:
-            expect = max(rep, loc) + bell
-        elif ordering == LF_REP:
-            expect = loc + rep + bell
-        else:                               # PARALLEL
-            expect = max(rep, loc) + 0.1 * min(loc, rep) + bell
-        assert total == pytest.approx(expect), \
-            f"{ordering}: {total} != {expect} (loc={loc} rep={rep})"
-        assert loc > 0 and rep > 0          # both components were real
-
-
-def test_parallel_cost_below_serial_sum_and_orderings_ranked():
-    """PARALLEL must now cost less than the serial sum it used to charge
-    (it still pays the contention penalty REP_LF does not)."""
-    totals = {o: _ordering_cost(o) for o in ORDERINGS}
-    par, (loc, rep, bell) = totals[PARALLEL]
-    assert par < loc + rep + 0.1 * min(loc, rep) + bell
-    assert totals[REP_LF][0] <= totals[PARALLEL][0]  # no contention term
 
 
 # --------------------------------------------------------------------- #
